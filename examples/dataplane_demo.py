@@ -19,6 +19,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.core import bnn, compile_bnn
 from repro.dataplane import (
     SwitchFabric,
@@ -29,6 +30,7 @@ from repro.dataplane import (
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--packets", type=int, default=1_000_000)
     ap.add_argument(

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs.n2net_paper import FIVE_TUPLE
 from repro.core import bitops, bnn, compile_bnn, throughput
 from repro.core.interpreter import run_program_jit
@@ -73,6 +74,7 @@ def train_bnn(pkts, labels, sizes, steps, lr=0.05, seed=0):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=400)
     ap.add_argument("--train-size", type=int, default=2048)

@@ -28,7 +28,7 @@ import json
 
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.dataplane import (
     FleetSpec,
     TenantSpec,
@@ -47,6 +47,7 @@ _SPEC = FleetSpec(tenants=(
 
 
 def main() -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--packets", type=int, default=60_000)
     ap.add_argument("--out", default="obs_out", help="artifact directory")

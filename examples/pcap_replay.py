@@ -30,6 +30,7 @@ import tempfile
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core.export import verify_roundtrip
 from repro.dataplane import FleetSpec, TenantSpec, build_fleet, pcap, traffic
 from repro.train.bnn_trainer import BnnTrainConfig, BnnTrainer, make_capture_task
@@ -40,6 +41,7 @@ SCENARIO_NAME = "pcap:replay"
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--packets", type=int, default=20_000)
     ap.add_argument("--steps", type=int, default=400)

@@ -12,11 +12,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import bnn, compile_bnn, run_program, throughput
 from repro.core.p4gen import generate_p4
 
 
 def main():
+    compile_cache.enable()
     spec = bnn.BnnSpec((32, 64, 32))     # dst-IP -> 64 -> 32 neurons
     params = bnn.init_params(spec, jax.random.PRNGKey(0))
 

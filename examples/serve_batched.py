@@ -14,6 +14,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.configs.base import QuantConfig
 from repro.models import init_params
@@ -43,6 +44,7 @@ def small(cfg):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="phi3-mini-3.8b")
     ap.add_argument("--quant", action="store_true",

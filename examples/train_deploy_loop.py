@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro import compile_cache
 from repro.core.export import verify_roundtrip
 from repro.train.bnn_trainer import BnnTrainConfig, BnnTrainer
 
@@ -29,6 +30,7 @@ MIN_HELD_OUT = 10_000
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--steps", type=int, default=600)
     ap.add_argument("--eval-per-class", type=int, default=MIN_HELD_OUT // 2)

@@ -15,6 +15,7 @@ import dataclasses
 
 import jax
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.configs.base import QuantConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -43,6 +44,7 @@ def reduced(cfg):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="phi3-mini-3.8b")
     ap.add_argument("--preset", choices=["reduced", "full"], default="reduced")

@@ -14,7 +14,7 @@ Backends:
 * ``"pallas"``— ``kernels.optable_exec`` kernel; production path on TPU,
   ``interpret=True`` elsewhere (tests).
 * ``"packed"``— bit-packed PHV path: activation bits are packed into uint32
-  lanes at parse time (``kernels.bitpack`` on TPU, scatter-add elsewhere)
+  lanes at parse time (one XLA scatter-add, the same on every device)
   and each neuron is one masked XNOR + ``population_count`` over 32 bits at
   a time instead of 32 op-table rows.  Requires a
   ``LoweredProgram.packed`` plan (compiler-built programs have one);
@@ -158,16 +158,22 @@ def _device_tables(lp: LoweredProgram) -> _DeviceTables:
 _PACKED_CACHE: dict[str, object] = {}
 
 
+def _pack_words(h: jax.Array, in_word, in_shift, n_words: int) -> jax.Array:
+    """Deposit (batch, bits) {0,1} uint32 into (batch, n_words) uint32 PHV
+    lanes: bit ``k`` lands in word ``in_word[k]`` at ``in_shift[k]``.  Bits
+    of one word are disjoint, so the scatter-add is an OR."""
+    words = jnp.zeros((h.shape[0], n_words), jnp.uint32)
+    return words.at[:, in_word].add(h << in_shift)
+
+
 def _packed_fn(lp: LoweredProgram):
     """Compile ``lp.packed`` into a jitted (batch, input_bits) {0,1} ->
     (batch, output_bits) int32 function, cached per program fingerprint.
 
-    Per layer: scatter the incoming bits into ``n_words`` uint32 PHV lanes
-    (via the ``kernels.bitpack`` pallas kernel on TPU when the layer has the
-    trivial contiguous layout, a one-hot scatter-add otherwise), then for
-    every neuron count agreements with one masked XNOR +
-    ``population_count`` per 32-bit word and compare against the SIGN
-    threshold.  Bit-exact with the op-table scan — the fuzz suite
+    Per layer: pack the incoming bits into ``n_words`` uint32 PHV lanes
+    (:func:`_pack_words`), then for every neuron count agreements with one
+    masked XNOR + ``population_count`` per 32-bit word and compare against
+    the SIGN threshold.  Bit-exact with the op-table scan — the fuzz suite
     (tests/test_differential_fuzz.py) holds the two together.
     """
     key = lp.fingerprint()
@@ -181,35 +187,23 @@ def _packed_fn(lp: LoweredProgram):
             "for hand-assembled tables and element slices); use the "
             "op-table backends"
         )
-    on_tpu = jax.default_backend() == "tpu"
-    layers = []
-    for pl_ in pp.layers:
-        trivial = bool(
-            np.array_equal(pl_.in_word, np.arange(pl_.n_in) // 32)
-            and np.array_equal(pl_.in_shift, np.arange(pl_.n_in) % 32)
-        )
-        layers.append((
+    layers = tuple(
+        (
             jnp.asarray(pl_.weights),
             jnp.asarray(pl_.thresholds),
             jnp.asarray(pl_.mask),
             jnp.asarray(pl_.in_word),
             jnp.asarray(pl_.in_shift),
             pl_.n_words,
-            trivial,
-        ))
-    layers = tuple(layers)
+        )
+        for pl_ in pp.layers
+    )
 
     @jax.jit
     def run(packets: jax.Array) -> jax.Array:
         h = packets.astype(jnp.uint32)  # (batch, bits in neuron order)
-        for w, thr, mask, in_word, in_shift, n_words, trivial in layers:
-            if trivial and on_tpu:
-                from repro.kernels.bitpack import pack_bits_words
-
-                words = pack_bits_words(h)
-            else:
-                words = jnp.zeros((h.shape[0], n_words), jnp.uint32)
-                words = words.at[:, in_word].add(h << in_shift)
+        for w, thr, mask, in_word, in_shift, n_words in layers:
+            words = _pack_words(h, in_word, in_shift, n_words)
             agree = jax.lax.population_count(
                 ~(words[:, None, :] ^ w[None, :, :]) & mask[None, :, :]
             )
@@ -266,10 +260,9 @@ def _packed_scan_fn(lp: LoweredProgram):
 
         def layer(h, tbl):
             w, thr, mask, in_word, in_shift = tbl
-            words = jnp.zeros((h.shape[0], max_words), jnp.uint32)
             # Pad input bits carry 0 (outputs past a layer's true width
             # never fire), so their word-0 scatter adds nothing.
-            words = words.at[:, in_word].add(h << in_shift)
+            words = _pack_words(h, in_word, in_shift, max_words)
             agree = jax.lax.population_count(
                 ~(words[:, None, :] ^ w[None, :, :]) & mask[None, :, :]
             )
@@ -954,6 +947,12 @@ def _rechunk(chunks: Iterable[np.ndarray], chunk_size: int) -> Iterator[np.ndarr
         yield np.concatenate(buf, axis=0) if len(buf) > 1 else buf[0]
 
 
+def _count_probe_error() -> None:
+    """A roofline probe or gauge publication failed: the run goes on, and
+    ``roofline.probe_errors_total`` says so."""
+    obs.registry().counter("roofline.probe_errors_total").inc()
+
+
 def _probe_roofline(lowered, backend, chunk, interpret, scan_hops):
     """Fail-soft ``roofline.dataplane`` probe of the compiled dispatch —
     obs-only bookkeeping, never allowed to affect an execution path."""
@@ -968,6 +967,7 @@ def _probe_roofline(lowered, backend, chunk, interpret, scan_hops):
             scan_hops=scan_hops,
         )
     except Exception:  # noqa: BLE001 - observation must not break runs
+        _count_probe_error()
         return None
 
 
@@ -978,7 +978,7 @@ def _record_roofline(roofline, measured_pps):
 
         _roofline_dp.record(roofline, measured_pps=measured_pps)
     except Exception:  # noqa: BLE001 - observation must not break runs
-        pass
+        _count_probe_error()
 
 
 def execute_stream(
@@ -1053,11 +1053,18 @@ def execute_stream(
         obs.registry().gauge("dataplane.stream_pps").set(total / seconds)
         if roofline is not None:
             _record_roofline(roofline, total / seconds)
+    outputs = None
+    if collect:  # an empty stream collects an empty array, not None
+        outputs = (
+            np.concatenate(collected, axis=0)
+            if collected
+            else np.zeros((0, lowered.output_bits), np.uint8)
+        )
     return StreamResult(
         packets=total,
         chunks=n_chunks,
         seconds=seconds,
         bit_counts=bit_counts,
-        outputs=np.concatenate(collected, axis=0) if collected else None,
+        outputs=outputs,
         warmup_seconds=warmup,
     )

@@ -217,6 +217,7 @@ def _probe_fleet_roofline(lowered, backend, n_streams, chunk, plan):
             devices=plan.devices,
         )
     except Exception:  # noqa: BLE001 - observation must not break runs
+        _executor._count_probe_error()
         return None
 
 

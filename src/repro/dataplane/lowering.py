@@ -74,8 +74,8 @@ def pack_bit_rows(bits: np.ndarray, n_words: int | None = None) -> np.ndarray:
     """Pack ``(..., n)`` {0,1} bits into ``(..., n_words)`` uint32 words.
 
     Little-endian within a word: logical bit ``k`` lands in word ``k // 32``
-    at shift ``k % 32`` — the packed-PHV word layout shared by the executor's
-    packed backend and ``kernels.bitpack`` (see docs/DATAPLANE.md).  Bits past
+    at shift ``k % 32`` — the packed-PHV word layout of the executor's
+    packed backend (see docs/DATAPLANE.md).  Bits past
     ``n`` are zero padding.
     """
     bits = np.asarray(bits)

@@ -60,37 +60,3 @@ def bitpack(
         out_shape=jax.ShapeDtypeStruct((m, kw), jnp.uint32),
         interpret=interpret,
     )(x)
-
-
-def pack_bits_words(
-    bits: jax.Array,
-    *,
-    block_m: int = 256,
-    block_kw: int = 16,
-    interpret: bool = False,
-) -> jax.Array:
-    """Pack ``(M, N)`` {0,1} bits into ``(M, ceil(N/32))`` uint32 words.
-
-    Any-width front end over :func:`bitpack` for the dataplane's packed-PHV
-    parse step: maps bits to ±1 signs (bit 1 -> +1 packs as 1), pads rows and
-    trailing bits (with -1, which packs as 0 — the packed-layout zero-padding
-    rule) up to the kernel's block divisibility, then slices the result back.
-    Word layout matches ``lowering.pack_bit_rows``: bit ``k`` -> word
-    ``k // 32``, shift ``k % 32``.
-    """
-    m, n = bits.shape
-    kw = max(1, -(-n // WORD))
-    if m == 0:
-        return jnp.zeros((0, kw), jnp.uint32)
-    bkw = min(block_kw, kw)
-    kw_padded = kw + (-kw) % bkw
-    bm = min(block_m, m)
-    m_padded = m + (-m) % bm
-    x = bits.astype(jnp.int32) * 2 - 1
-    x = jnp.pad(
-        x,
-        ((0, m_padded - m), (0, kw_padded * WORD - n)),
-        constant_values=-1,
-    )
-    out = bitpack(x, block_m=bm, block_kw=bkw, interpret=interpret)
-    return out[:m, :kw]

@@ -12,9 +12,11 @@ output block's index map ignores the element index, so the register block
 stays resident in VMEM across the whole program for each batch block (the
 same accumulator-residency pattern as ``bnn_matmul``).  Per element the
 kernel makes two passes over the rows — compute into a scratch buffer, then
-write back — preserving RMT's read-before-write semantics.  Scalar tables
-(one row per grid step) live in SMEM; uint32 immediates travel bitcast as
-int32 and are bitcast back per scalar.
+write back — preserving RMT's read-before-write semantics.  The eight
+scalar tables travel as one ``(num_elements, 8, rows)`` int32 stack, one
+element's slab per grid step in SMEM (``8 * rows * 4`` bytes, double
+buffered); uint32 immediates are bitcast to int32 outside the kernel and
+converted back per scalar inside it.
 """
 from __future__ import annotations
 
@@ -37,10 +39,11 @@ _ALL_OPS = (
 )
 
 
-def _kernel(
-    opc_ref, dst_ref, s0_ref, s1_ref, i0_ref, i1_ref, m_ref, fw_ref,
-    regs_ref, out_ref, scratch_ref, *, rows: int, used: tuple,
-):
+# Row order of the stacked scalar table (see ``optable_run``).
+_OPC, _DST, _SRC0, _SRC1, _IMM0, _IMM1, _MASK, _FIRST = range(8)
+
+
+def _kernel(tab_ref, regs_ref, out_ref, scratch_ref, *, rows: int, used: tuple):
     e = pl.program_id(1)
 
     @pl.when(e == 0)
@@ -52,15 +55,16 @@ def _kernel(
         # keeps the kernels package from depending on dataplane at load time.
         from repro.dataplane.executor import alu_variants
 
-        opc = opc_ref[0, r]
-        s0 = s0_ref[0, r]
-        s1 = s1_ref[0, r]
-        i0 = jax.lax.bitcast_convert_type(i0_ref[0, r], jnp.uint32)
-        i1 = jax.lax.bitcast_convert_type(i1_ref[0, r], jnp.uint32)
-        m = jax.lax.bitcast_convert_type(m_ref[0, r], jnp.uint32)
+        opc = tab_ref[_OPC, r]
+        # Immediates travel as int32 (SMEM holds 32-bit words); the signed to
+        # unsigned convert keeps every bit, and unlike a bitcast it is legal
+        # on a scalar.
+        i0 = tab_ref[_IMM0, r].astype(jnp.uint32)
+        i1 = tab_ref[_IMM1, r].astype(jnp.uint32)
+        m = tab_ref[_MASK, r].astype(jnp.uint32)
 
-        r0 = out_ref[pl.ds(s0, 1), :]
-        r1 = out_ref[pl.ds(s1, 1), :]
+        r0 = out_ref[pl.ds(tab_ref[_SRC0, r], 1), :]
+        r1 = out_ref[pl.ds(tab_ref[_SRC1, r], 1), :]
 
         variants = alu_variants(r0, r1, i0, i1, used)
         _, val = variants[0]
@@ -72,13 +76,14 @@ def _kernel(
     jax.lax.fori_loop(0, rows, compute_row, 0)
 
     def write_row(r, carry):
-        dst = dst_ref[0, r]
-        first = fw_ref[0, r]
+        dst = tab_ref[_DST, r]
         val = scratch_ref[pl.ds(r, 1), :]
         cur = out_ref[pl.ds(dst, 1), :]
         # First writer of a slot overwrites; FOLD continuation rows deposit
         # additional (disjoint) bits additively.
-        out_ref[pl.ds(dst, 1), :] = jnp.where(first == 1, val, cur + val)
+        out_ref[pl.ds(dst, 1), :] = jnp.where(
+            tab_ref[_FIRST, r] == 1, val, cur + val
+        )
         return carry
 
     jax.lax.fori_loop(0, rows, write_row, 0)
@@ -116,25 +121,31 @@ def optable_run(
         regs = jnp.pad(regs, ((0, 0), (0, pad)))
     padded = batch + pad
 
+    # One (num_elements, 8, rows) int32 stack: each grid step's block is one
+    # element's (8, rows) slab, whose last two dims are the array's own —
+    # the TPU tiling rule for a block that is not a multiple of (8, 128).
     as_i32 = functools.partial(jax.lax.bitcast_convert_type, new_dtype=jnp.int32)
+    tables = jnp.stack(
+        [
+            opcode, dst, src0, src1,
+            as_i32(imm0), as_i32(imm1), as_i32(mask), first_write,
+        ],
+        axis=1,
+    ).astype(jnp.int32)
     table_spec = pl.BlockSpec(
-        (1, rows), lambda b, e: (e, 0), memory_space=pltpu.SMEM
+        (None, 8, rows), lambda b, e: (e, 0, 0), memory_space=pltpu.SMEM
     )
     regs_spec = pl.BlockSpec((num_regs, bb), lambda b, e: (0, b))
 
     out = pl.pallas_call(
         functools.partial(_kernel, rows=rows, used=tuple(used)),
         grid=(padded // bb, num_el),
-        in_specs=[table_spec] * 8 + [regs_spec],
+        in_specs=[table_spec, regs_spec],
         out_specs=regs_spec,
         out_shape=jax.ShapeDtypeStruct((num_regs, padded), jnp.uint32),
         scratch_shapes=[pltpu.VMEM((rows, bb), jnp.uint32)],
         interpret=interpret,
-    )(
-        opcode, dst, src0, src1,
-        as_i32(imm0), as_i32(imm1), as_i32(mask), first_write,
-        regs,
-    )
+    )(tables, regs)
     return out[:, :batch] if pad else out
 
 

@@ -142,8 +142,6 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str, out_dir: str,
 
     mem = compiled.memory_analysis()
     xla_cost = compiled.cost_analysis() or {}
-    if isinstance(xla_cost, (list, tuple)):  # older jax: one dict per program
-        xla_cost = xla_cost[0] if xla_cost else {}
     text = compiled.as_text()
     costs = hlo.analyze(text)
 

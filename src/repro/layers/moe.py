@@ -31,17 +31,6 @@ from repro.configs.base import ModelConfig
 from repro.layers.ffn import ffn_apply, ffn_init
 from repro.layers.linear import dense_init
 
-# jax >= 0.6 promotes shard_map to jax.shard_map (check_vma=); older releases
-# ship it as jax.experimental.shard_map.shard_map (check_rep=).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NO_CHECK = {"check_vma": False}
-else:  # pragma: no cover - exercised on older jax only
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_NO_CHECK = {"check_rep": False}
-
-
 def _pack_experts(w: jax.Array) -> tuple[jax.Array, jax.Array]:
     """(E, out, in) latent -> ((E, out, ceil(in/32)) uint32, (E, out) alpha)."""
     e, o, i = w.shape
@@ -253,7 +242,7 @@ def _moe_shard_map(params, x2, idx, gates, cfg: ModelConfig, mesh, bs) -> jax.Ar
         )
         return jax.lax.psum(part, "model")
 
-    return _shard_map(
+    return jax.shard_map(
         block,
         mesh=mesh,
         in_specs=(
@@ -265,5 +254,5 @@ def _moe_shard_map(params, x2, idx, gates, cfg: ModelConfig, mesh, bs) -> jax.Ar
             wspec(1),
         ),
         out_specs=P(tok_spec[0], None),
-        **_SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )(x2, idx, gates, params["w_gate"], params["w_up"], params["w_down"])

@@ -8,7 +8,8 @@ All three inputs come from the scan-aware HLO analyzer (``roofline.hlo``),
 because XLA's ``cost_analysis()`` counts while-loop bodies once (verified —
 see hlo.py docstring).  The analyzer returns PER-DEVICE numbers (the module
 is the SPMD-partitioned program), so the compute/memory terms divide by the
-per-chip peaks only; "chips" is retained in the report for context.
+per-chip peaks only; "chips" is retained in the report for context.  The
+dry run models a TPU v5e pod, so the peaks are v5e's, asked for by name.
 """
 from __future__ import annotations
 
@@ -16,6 +17,8 @@ import dataclasses
 
 from repro.roofline import hw
 from repro.roofline.hlo import HloCosts, analyze  # noqa: F401 (re-export)
+
+_PEAKS = hw.peaks(hw.V5E_KIND)
 
 
 @dataclasses.dataclass
@@ -61,7 +64,7 @@ class Roofline:
         t = self.step_time_s
         if t <= 0:
             return 0.0
-        return self.model_flops / t / (self.chips * hw.PEAK_FLOPS_BF16)
+        return self.model_flops / t / (self.chips * _PEAKS.flops_bf16)
 
     def row(self) -> dict:
         return {
@@ -106,9 +109,9 @@ def build(
         hlo_bytes=costs.bytes,
         collective_bytes_per_chip=costs.collective_bytes,
         model_flops=model_flops,
-        compute_s=costs.flops / hw.PEAK_FLOPS_BF16,
-        memory_s=costs.bytes / hw.HBM_BW,
-        collective_s=costs.collective_bytes / hw.ICI_LINK_BW,
+        compute_s=costs.flops / _PEAKS.flops_bf16,
+        memory_s=costs.bytes / _PEAKS.hbm_bw,
+        collective_s=costs.collective_bytes / _PEAKS.ici_link_bw,
         collective_detail=dict(costs.collective_detail),
         per_device_hbm_bytes=per_device_hbm_bytes,
         xla_cost_flops=xla_cost_flops,

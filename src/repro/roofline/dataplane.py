@@ -3,9 +3,12 @@
 Bridges the dormant HLO cost analyzer (``roofline.hlo``) into the
 dataplane: AOT-lower the *exact* jitted function a stream/fleet run
 dispatches, analyze its HLO for per-dispatch FLOPs and bytes, and turn
-the TPU v5e roofline (``roofline.hw``) into a **packets-per-second upper
-bound** — so "fast as the hardware allows" is a number next to every
-measured rate.
+the roofline of the device it runs on (``roofline.hw``, keyed by
+``device_kind``) into a **packets-per-second upper bound** — so "fast as
+the hardware allows" is a number next to every measured rate.  Off-TPU no
+published peak applies: the probe still records the HLO costs, but the
+bound is infinite and no ``pps_bound``/``fraction`` gauge is published.  On
+a TPU whose kind the table lacks, the probe raises.
 
 A BNN dataplane executable has essentially no dot FLOPs (XNOR +
 popcount lowers to elementwise integer ops), so MFU is meaningless here;
@@ -57,6 +60,7 @@ class DataplaneRoofline:
     hlo_flops: float
     hlo_bytes: float
     collective_bytes: float
+    peaks: hw.ChipPeaks | None = None  # None: no published peak (off-TPU)
 
     @property
     def packets(self) -> int:
@@ -65,15 +69,17 @@ class DataplaneRoofline:
 
     @property
     def compute_s(self) -> float:
-        return self.hlo_flops / hw.PEAK_FLOPS_BF16
+        return self.hlo_flops / self.peaks.flops_bf16 if self.peaks else 0.0
 
     @property
     def memory_s(self) -> float:
-        return self.hlo_bytes / hw.HBM_BW
+        return self.hlo_bytes / self.peaks.hbm_bw if self.peaks else 0.0
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / hw.ICI_LINK_BW
+        if not self.peaks:
+            return 0.0
+        return self.collective_bytes / self.peaks.ici_link_bw
 
     @property
     def step_time_s(self) -> float:
@@ -82,6 +88,8 @@ class DataplaneRoofline:
 
     @property
     def bottleneck(self) -> str:
+        if not self.peaks:
+            return "unknown"
         terms = {
             "compute": self.compute_s,
             "memory": self.memory_s,
@@ -109,6 +117,8 @@ class DataplaneRoofline:
 
 def _build(key: tuple, path: str, lowered, chunk: int, streams: int,
            costs: HloCosts) -> "DataplaneRoofline":
+    import jax
+
     rf = DataplaneRoofline(
         path=path,
         fingerprint=lowered.fingerprint(),
@@ -117,6 +127,7 @@ def _build(key: tuple, path: str, lowered, chunk: int, streams: int,
         hlo_flops=costs.flops,
         hlo_bytes=costs.bytes,
         collective_bytes=costs.collective_bytes,
+        peaks=hw.device_peaks(jax.devices()[0]),
     )
     _CACHE[key] = rf
     return rf
@@ -207,6 +218,8 @@ def record(rf: DataplaneRoofline, measured_pps: float | None = None) -> None:
     m.gauge("roofline.hlo_bytes", path=rf.path).set(rf.hlo_bytes)
     m.gauge("roofline.hlo_flops", path=rf.path).set(rf.hlo_flops)
     m.gauge("roofline.bytes_per_packet", path=rf.path).set(rf.bytes_per_packet)
+    if rf.peaks is None:
+        return
     m.gauge("roofline.pps_bound", path=rf.path).set(rf.roofline_pps)
     if measured_pps is not None and measured_pps > 0:
         m.gauge("roofline.fraction", path=rf.path).set(rf.fraction(measured_pps))

@@ -340,9 +340,8 @@ class FleetEngine:
             now = self._clock()
         q = self._queue
         windowed = self._agg_rate.rate(now)
-        bound = (
-            self._roofline.roofline_pps if self._roofline is not None else None
-        )
+        rf = self._roofline
+        bound = rf.roofline_pps if rf is not None and rf.peaks else None
         return FleetHealth(
             now=now,
             streams=len(self._stream_rates),

@@ -222,17 +222,6 @@ def activation_spec(mesh: Mesh, batch: int) -> P:
 # Fleet sharding (dataplane): streams over a 1-D device mesh
 # ---------------------------------------------------------------------------
 
-# jax >= 0.6 promotes shard_map to jax.shard_map (check_vma=); older releases
-# ship it as jax.experimental.shard_map.shard_map (check_rep=).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NO_CHECK = {"check_vma": False}
-else:  # pragma: no cover - exercised on older jax only
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_NO_CHECK = {"check_rep": False}
-
-
 def fleet_mesh(num_devices: Optional[int] = None) -> Mesh:
     """1-D device mesh over a ``fleet`` axis for batched stream serving.
 
@@ -257,6 +246,6 @@ def shard_streams(fn, mesh: Mesh):
     ``fn`` on its local slice of streams (no collectives — streams never
     communicate, exactly like the independent switches they simulate)."""
     spec = P("fleet")
-    return _shard_map(
-        fn, mesh=mesh, in_specs=(spec,), out_specs=spec, **_SHARD_MAP_NO_CHECK
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False
     )

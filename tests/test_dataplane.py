@@ -166,6 +166,17 @@ def test_stream_equals_batch_and_counts_bits():
     assert sr.packets_per_second > 0
 
 
+@pytest.mark.parametrize("collect", [True, False])
+def test_empty_stream_collects_an_empty_array(collect):
+    _, prog = _compiled((16, 8, 4))
+    sr = execute_stream(lower_program(prog), iter([]), collect=collect)
+    assert sr.packets == 0 and sr.chunks == 0
+    if collect:
+        assert sr.outputs.shape == (0, 4) and sr.outputs.dtype == np.uint8
+    else:
+        assert sr.outputs is None
+
+
 def test_rechunk_reslices_exactly():
     chunks = [np.arange(n)[:, None] for n in (5, 1, 9, 2)]
     out = list(_rechunk(iter(chunks), 4))

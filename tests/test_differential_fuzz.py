@@ -15,6 +15,7 @@ count (CI pins 200); failing case reprs land in ``$FUZZ_ARTIFACT_DIR``.
 """
 from __future__ import annotations
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -235,16 +236,20 @@ def test_threshold_extremes_never_and_always_fire():
         )
 
 
-def test_bitpack_kernel_matches_reference_packing():
-    """The Pallas pack kernel (interpret mode off-TPU) agrees with the numpy
-    word-layout reference for ragged shapes."""
-    from repro.kernels.bitpack import pack_bits_words
-
+def test_packed_word_packing_matches_reference_packing():
+    """The packed backend's word packing agrees with the numpy word-layout
+    reference for ragged shapes."""
     rng = np.random.default_rng(12)
     for m, n in [(1, 1), (13, 45), (256, 32), (7, 96), (300, 17)]:
         bits = rng.integers(0, 2, (m, n)).astype(np.int32)
-        packed = np.asarray(pack_bits_words(bits, interpret=True))
-        np.testing.assert_array_equal(packed, pack_bit_rows(bits))
+        k = np.arange(n)
+        packed = executor._pack_words(
+            jnp.asarray(bits, jnp.uint32),
+            jnp.asarray(k // 32),
+            jnp.asarray(k % 32, jnp.uint32),
+            -(-n // 32),
+        )
+        np.testing.assert_array_equal(np.asarray(packed), pack_bit_rows(bits))
 
 
 def test_opcode_runs_cover_all_elements():
