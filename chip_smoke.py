@@ -366,18 +366,23 @@ def phase_fleet(sz: Sizes, seed: int, tally: Tally) -> dict:
 
 
 def phase_observability(sz: Sizes, seed: int, tally: Tally) -> dict:
-    """(e) The stream and fleet paths again with ``repro.obs`` on: the
-    roofline probes must publish bounds for the TPU paths, and none may
-    fail."""
+    """(e) The stream, fleet and ``FleetEngine`` paths again with
+    ``repro.obs`` on: outputs stay exact, every served chunk passes through
+    each phase span once, lowerings are counted per span, and
+    ``FleetEngine``'s roofline probe reports a bound for the TPU paths
+    without failing."""
     from repro import obs
     from repro.dataplane import Backend, ExecutionPlan, generate, run
-    from repro.dataplane.executor import resolve_backend
+    from repro.dataplane.executor import PHASES, resolve_backend
+    from repro.serving.engine import FleetEngine
 
     weights, lp = headline_program(seed)
     x = generate("ddos_burst", sz.obs_packets, lp.input_bits, seed=seed)
     want = oracle(weights, x)
     streams = fleet_streams(sz, seed, lp.input_bits)
     fleet_wants = [oracle(weights, s) for s in streams]
+    chunks = 0
+    bounds = {}
     obs.enable(reset=True)
     try:
         for backend in (Backend.AUTO, Backend.PACKED):
@@ -389,49 +394,51 @@ def phase_observability(sz: Sizes, seed: int, tally: Tally) -> dict:
                 ),
             )
             tally.compare(f"obs stream[{backend.value}]", res.outputs, want)
-            fl = run(
-                lp,
-                streams,
-                plan=ExecutionPlan(
-                    backend=backend,
-                    chunk_size=sz.fleet_chunk,
-                    fleet=len(streams),
-                    collect=True,
-                ),
+            plan = ExecutionPlan(
+                backend=backend,
+                chunk_size=sz.fleet_chunk,
+                fleet=len(streams),
+                collect=True,
             )
+            fl = run(lp, streams, plan=plan)
+            eng = FleetEngine(lp, plan=plan)
+            served = eng.serve(streams, collect=True)
             for i, w in enumerate(fleet_wants):
                 tally.compare(
                     f"obs fleet[{backend.value}] stream {i}", fl.outputs[i], w
                 )
+                tally.compare(
+                    f"obs FleetEngine[{backend.value}] stream {i}",
+                    served.outputs[i], w,
+                )
+            bounds[backend.value] = eng.health().roofline_pps_bound
+            chunks += res.chunks + fl.chunks
         snap = obs.registry().snapshot()
+        records = list(obs.tracer().records)
         errors = obs.registry().counter("roofline.probe_errors_total").value
     finally:
         obs.disable()
-    bounds = {
-        row["labels"].get("path"): row["value"]
+    served = [r for r in records if r.cat == "phase" and not r.args.get("warm")]
+    for phase in PHASES:
+        # one of each per served chunk; one more ingest per run finds it dry
+        extra = 4 if phase == "ingest" else 0
+        n = sum(r.name == phase for r in served)
+        tally.require(f"{n} {phase} spans for {chunks} chunks", n == chunks + extra)
+    lowerings = {
+        row["labels"].get("span"): row["value"]
         for row in snap
-        if row["name"] == "roofline.pps_bound"
+        if row["name"] == "jax.lowerings_total"
     }
-    fractions = {
-        row["labels"].get("path")
-        for row in snap
-        if row["name"] == "roofline.fraction"
-    }
-    auto = resolve_backend("auto")
-    expected = {auto, "packed", f"fleet{len(streams)}:{auto}",
-                f"fleet{len(streams)}:packed"}
     tally.require(f"roofline.probe_errors_total = {errors}", errors == 0)
-    tally.require(
-        f"roofline.pps_bound missing for {sorted(expected - set(bounds))}",
-        expected <= set(bounds),
-    )
-    tally.require(
-        f"roofline.fraction missing for {sorted(expected - fractions)}",
-        expected <= fractions,
-    )
+    missing = sorted(b for b, bound in bounds.items() if bound is None)
+    tally.require(f"FleetEngine.health() has no roofline bound for {missing}",
+                  not missing)
     return {
         "probe_errors": errors,
-        "pps_bound_paths": sorted(p for p in bounds if p),
+        "fleet_pps_bound": {
+            f"fleet{len(streams)}:{resolve_backend(b)}": v for b, v in bounds.items()
+        },
+        "lowerings_by_span": lowerings,
     }
 
 

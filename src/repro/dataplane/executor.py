@@ -32,7 +32,11 @@ fixed-size blocks so millions of packets run at constant device memory and a
 single compiled executable.  The stream path is instrumented through
 ``repro.obs`` (packets/chunk counters, chunk-latency histogram, and
 ``compile:``/``execute:`` spans) — all no-ops unless the global
-observability switch is on (see ``docs/OBSERVABILITY.md``).
+observability switch is on (see ``docs/OBSERVABILITY.md``).  Each chunk of
+:func:`execute_stream` and :func:`execute` passes through five phase spans,
+``ingest``, ``h2d``, ``dispatch``, ``d2h`` and ``collect`` (:data:`PHASES`),
+each carrying ``chunk=<k>``; while a JAX profiler session collects they are
+written into its trace, so the device's idle time can be split among them.
 
 Routed parse/deparse (:func:`parse_packets_routed`,
 :func:`deparse_regs_routed`) generalize the parser to per-packet program
@@ -68,6 +72,11 @@ from repro.dataplane import lowering
 from repro.dataplane.lowering import LoweredProgram
 
 DEFAULT_CHUNK = 1 << 15  # 32768 packets per device dispatch
+
+# The phases of one served chunk, in order: pull and pad it on the host,
+# copy it in, call the compiled dispatch, wait for and copy the result
+# back, fold it into the run's outputs.
+PHASES = ("ingest", "h2d", "dispatch", "d2h", "collect")
 
 _BACKENDS = ("auto", "jnp", "pallas", "packed")
 _BACKEND_ALIASES = {"fused": "jnp"}
@@ -896,19 +905,27 @@ def execute(
     backend = resolve_backend(backend)
     n = packets.shape[0]
     chunk = chunk_size or DEFAULT_CHUNK
-    if n <= chunk:
-        return np.asarray(
-            _run_chunk(lowered, jnp.asarray(packets), backend, interpret, scan_hops)
-        )[:n]
-
-    out = np.empty((n, lowered.output_bits), np.int32)
-    for start in range(0, n, chunk):
-        block = packets[start : start + chunk]
-        pad = chunk - block.shape[0]
-        if pad:
-            block = np.pad(block, ((0, pad), (0, 0)))
-        res = _run_chunk(lowered, jnp.asarray(block), backend, interpret, scan_hops)
-        out[start : start + chunk] = np.asarray(res)[: chunk - pad]
+    if n <= chunk:  # a batch that fits runs unpadded, as one chunk
+        size, starts, out = n, (0,), None
+    else:
+        size, starts = chunk, range(0, n, chunk)
+        out = np.empty((n, lowered.output_bits), np.int32)
+    for k, start in enumerate(starts):
+        with obs.span("ingest", cat="phase", chunk=k):
+            block = packets[start : start + size]
+            pad = size - block.shape[0]
+            if pad:
+                block = np.pad(block, ((0, pad), (0, 0)))
+        with obs.span("h2d", cat="phase", chunk=k):
+            dev = jnp.asarray(block)
+        with obs.span("dispatch", cat="phase", chunk=k):
+            res = _run_chunk(lowered, dev, backend, interpret, scan_hops)
+        with obs.span("d2h", cat="phase", chunk=k):
+            res = np.asarray(res)
+        with obs.span("collect", cat="phase", chunk=k):
+            if out is None:  # the only chunk: its rows are the result
+                return res[:n]
+            out[start : start + size] = res[: size - pad]
     return out
 
 
@@ -947,40 +964,6 @@ def _rechunk(chunks: Iterable[np.ndarray], chunk_size: int) -> Iterator[np.ndarr
         yield np.concatenate(buf, axis=0) if len(buf) > 1 else buf[0]
 
 
-def _count_probe_error() -> None:
-    """A roofline probe or gauge publication failed: the run goes on, and
-    ``roofline.probe_errors_total`` says so."""
-    obs.registry().counter("roofline.probe_errors_total").inc()
-
-
-def _probe_roofline(lowered, backend, chunk, interpret, scan_hops):
-    """Fail-soft ``roofline.dataplane`` probe of the compiled dispatch —
-    obs-only bookkeeping, never allowed to affect an execution path."""
-    try:
-        from repro.roofline import dataplane as _roofline_dp
-
-        return _roofline_dp.probe_stream(
-            lowered,
-            backend=backend,
-            chunk=chunk,
-            interpret=interpret,
-            scan_hops=scan_hops,
-        )
-    except Exception:  # noqa: BLE001 - observation must not break runs
-        _count_probe_error()
-        return None
-
-
-def _record_roofline(roofline, measured_pps):
-    """Fail-soft gauge publication for a probe (see ``_probe_roofline``)."""
-    try:
-        from repro.roofline import dataplane as _roofline_dp
-
-        _roofline_dp.record(roofline, measured_pps=measured_pps)
-    except Exception:  # noqa: BLE001 - observation must not break runs
-        _count_probe_error()
-
-
 def execute_stream(
     lowered: LoweredProgram,
     chunks: Iterable[np.ndarray],
@@ -994,10 +977,14 @@ def execute_stream(
     """Stream a packet-chunk iterator through the executor.
 
     With ``collect=False`` (default) only aggregate statistics are kept —
-    memory stays constant no matter how many packets flow.  Timing covers
-    device execution including host transfer (``block_until_ready`` via
-    ``np.asarray``), not trace/compile of the first chunk — that warm call
-    is reported separately as ``warmup_seconds``.
+    memory stays constant no matter how many packets flow.  ``seconds``
+    reads each chunk from the start of its ``ingest`` phase (pulling it
+    from ``chunks`` and padding it) to the end of its ``collect`` phase
+    (folding its verdicts into the result): the host-to-device copy, the
+    dispatch and the wait for the device with the copy back all fall
+    inside.  The first chunk is dispatched twice; its first, warm call
+    (trace and compile) is left out of ``seconds`` and reported as
+    ``warmup_seconds``.
     """
     backend = resolve_backend(backend)
     bit_counts = np.zeros(lowered.output_bits, np.int64)
@@ -1006,42 +993,48 @@ def execute_stream(
     n_chunks = 0
     seconds = 0.0
     warmup = 0.0
-    roofline = None
+    blocks = _rechunk(chunks, chunk_size)
     with obs.span(
         "stream:execute_stream", cat="stream",
         backend=backend, chunk_size=chunk_size,
     ):
-        for block in _rechunk(chunks, chunk_size):
-            n = block.shape[0]
-            pad = chunk_size - n
-            if pad:
-                block = np.pad(block, ((0, pad), (0, 0)))
-            dev = jnp.asarray(block)
-            if n_chunks == 0:  # warm the compile cache outside the clock
+        while True:
+            k = n_chunks
+            t0 = time.perf_counter()
+            with obs.span("ingest", cat="phase", chunk=k):
+                block = next(blocks, None)
+                if block is not None:
+                    n = block.shape[0]
+                    pad = chunk_size - n
+                    if pad:
+                        block = np.pad(block, ((0, pad), (0, 0)))
+            if block is None:
+                break
+            with obs.span("h2d", cat="phase", chunk=k):
+                dev = jnp.asarray(block)
+            warm = 0.0
+            if k == 0:  # warm the compile cache outside the clock
                 with obs.span(
                     "compile:stream_chunk", cat="compile",
                     backend=backend, packets=chunk_size,
-                ):
+                ), obs.span("dispatch", cat="phase", chunk=k, warm=True):
                     w0 = time.perf_counter()
                     _run_chunk(
                         lowered, dev, backend, interpret, scan_hops
                     ).block_until_ready()
-                    warmup = time.perf_counter() - w0
-                if obs.enabled():  # cost the compiled dispatch, once
-                    roofline = _probe_roofline(
-                        lowered, backend, chunk_size, interpret, scan_hops
-                    )
+                    warm = warmup = time.perf_counter() - w0
             with obs.span("execute:stream_chunk", cat="execute", packets=n):
-                t0 = time.perf_counter()
-                res = np.asarray(
-                    _run_chunk(lowered, dev, backend, interpret, scan_hops)
-                )
-                dt = time.perf_counter() - t0
+                with obs.span("dispatch", cat="phase", chunk=k):
+                    res = _run_chunk(lowered, dev, backend, interpret, scan_hops)
+                with obs.span("d2h", cat="phase", chunk=k):
+                    res = np.asarray(res)
+            with obs.span("collect", cat="phase", chunk=k):
+                res = res[:n]
+                bit_counts += res.sum(axis=0, dtype=np.int64)
+                if collect:
+                    collected.append(res.astype(np.uint8))
+            dt = time.perf_counter() - t0 - warm
             seconds += dt
-            res = res[:n]
-            bit_counts += res.sum(axis=0, dtype=np.int64)
-            if collect:
-                collected.append(res.astype(np.uint8))
             total += n
             n_chunks += 1
             if obs.enabled():
@@ -1051,8 +1044,6 @@ def execute_stream(
                 m.histogram("dataplane.chunk_seconds").observe(dt)
     if obs.enabled() and seconds > 0:
         obs.registry().gauge("dataplane.stream_pps").set(total / seconds)
-        if roofline is not None:
-            _record_roofline(roofline, total / seconds)
     outputs = None
     if collect:  # an empty stream collects an empty array, not None
         outputs = (

@@ -25,7 +25,8 @@ fuzz suite (``tests/test_fleet.py``) holds fleet, single-stream, and the
 interpreter oracle together, including mid-stream resume.
 
 Entry points: :func:`execute_fleet` (stats + optional per-stream outputs,
-same warmup-outside-the-clock timing discipline as ``execute_stream``) and
+the same warmup-outside-the-clock timing discipline and the same phase
+spans per block as ``execute_stream``) and
 :func:`fleet_fn` (the raw compiled callable, used by ``serving.engine``'s
 async pipeline).  Reach both through ``repro.dataplane.run(program, streams,
 plan=ExecutionPlan(fleet=N, ...))``.
@@ -201,26 +202,6 @@ def _normalize_streams(streams, fleet: int | None) -> list:
     ]
 
 
-def _probe_fleet_roofline(lowered, backend, n_streams, chunk, plan):
-    """Fail-soft roofline probe of the vmapped fleet dispatch — obs-only
-    bookkeeping, never allowed to affect an execution path."""
-    try:
-        from repro.roofline import dataplane as _roofline_dp
-
-        return _roofline_dp.probe_fleet(
-            lowered,
-            backend=backend,
-            streams=n_streams,
-            chunk=chunk,
-            interpret=plan.interpret,
-            scan_hops=bool(plan.scan_hops),
-            devices=plan.devices,
-        )
-    except Exception:  # noqa: BLE001 - observation must not break runs
-        _executor._count_probe_error()
-        return None
-
-
 def execute_fleet(
     lowered,
     streams,
@@ -231,10 +212,12 @@ def execute_fleet(
     shard_map-ed) executor; bit-exact per stream with
     ``executor.execute(lowered, stream_i)``.
 
-    Timing follows ``execute_stream``'s discipline: the first block's warm
-    call (trace + compile) happens outside the clock and is reported as
-    ``warmup_seconds``; host->device transfer of each block is also outside
-    the per-block timer.
+    Timing follows ``execute_stream``'s discipline: ``seconds`` reads each
+    block from the start of its ``ingest`` phase (``fleet_blocks`` filling
+    it stream by stream) to the end of its ``collect`` phase (the
+    per-stream verdicts folded in), with the host-to-device copy, the
+    dispatch and the copy back inside; the first block's warm call (trace
+    and compile) is left out and reported as ``warmup_seconds``.
     """
     if not isinstance(lowered, LoweredProgram):
         lowered = lower_program(lowered)
@@ -262,44 +245,52 @@ def execute_fleet(
     seconds = 0.0
     warmup = 0.0
     n_blocks = 0
-    roofline = None
+    blocks_in = fleet_blocks(its, chunk, lowered.input_bits)
     with obs.span(
         "stream:fleet_run", cat="stream",
         streams=n_streams, backend=backend, chunk_size=chunk,
         devices=plan.devices or 1,
     ):
-        for blocks, valid in fleet_blocks(its, chunk, lowered.input_bits):
-            dev = jnp.asarray(blocks)
-            if n_blocks == 0:  # warm the compile cache outside the clock
+        while True:
+            k = n_blocks
+            t0 = time.perf_counter()
+            with obs.span("ingest", cat="phase", chunk=k):
+                item = next(blocks_in, None)
+            if item is None:
+                break
+            blocks, valid = item
+            with obs.span("h2d", cat="phase", chunk=k):
+                dev = jnp.asarray(blocks)
+            warm = 0.0
+            if k == 0:  # warm the compile cache outside the clock
                 with obs.span(
                     "compile:fleet_chunk", cat="compile",
                     streams=n_streams, packets=n_streams * chunk,
-                ):
+                ), obs.span("dispatch", cat="phase", chunk=k, warm=True):
                     w0 = time.perf_counter()
                     fn(dev).block_until_ready()
-                    warmup = time.perf_counter() - w0
-                if obs.enabled():  # cost the compiled dispatch, once
-                    roofline = _probe_fleet_roofline(
-                        lowered, backend, n_streams, chunk, plan
-                    )
+                    warm = warmup = time.perf_counter() - w0
             served = int(valid.sum())
             with obs.span(
                 "execute:fleet_chunk", cat="execute", packets=served
             ):
-                t0 = time.perf_counter()
-                res = np.asarray(fn(dev))
-                dt = time.perf_counter() - t0
+                with obs.span("dispatch", cat="phase", chunk=k):
+                    res = fn(dev)
+                with obs.span("d2h", cat="phase", chunk=k):
+                    res = np.asarray(res)
+            with obs.span("collect", cat="phase", chunk=k):
+                for i in range(n_streams):
+                    v = int(valid[i])
+                    if not v:
+                        continue
+                    rows = res[i, :v]
+                    bit_counts += rows.sum(axis=0, dtype=np.int64)
+                    per_stream[i] += v
+                    if collected is not None:
+                        collected[i].append(rows.astype(np.uint8))
+            dt = time.perf_counter() - t0 - warm
             seconds += dt
             n_blocks += 1
-            for i in range(n_streams):
-                v = int(valid[i])
-                if not v:
-                    continue
-                rows = res[i, :v]
-                bit_counts += rows.sum(axis=0, dtype=np.int64)
-                per_stream[i] += v
-                if collected is not None:
-                    collected[i].append(rows.astype(np.uint8))
             if obs.enabled():
                 m = obs.registry()
                 m.counter("fleet.packets_total").inc(served)
@@ -308,8 +299,6 @@ def execute_fleet(
     total = int(per_stream.sum())
     if obs.enabled() and seconds > 0:
         obs.registry().gauge("fleet.agg_pps").set(total / seconds)
-        if roofline is not None:
-            _executor._record_roofline(roofline, total / seconds)
     outputs = None
     if collected is not None:
         outputs = [

@@ -103,12 +103,15 @@ def optable_run(
     used: tuple | None = None,
     block_b: int = 256,
     interpret: bool = False,
+    name: str | None = None,
 ) -> jax.Array:
     """Run the op-table over a transposed register file.
 
     ``regs``: (num_regs, batch) uint32 — parsed packets, one column each.
     Tables: (num_elements, max_rows) as produced by ``lowering``.  Returns
-    the final (num_regs, batch) register file.
+    the final (num_regs, batch) register file.  ``name`` names the kernel
+    in the compiled program and the device trace; it defaults to the
+    element range the tables cover, ``optable_e0_<num_elements>``.
     """
     num_regs, batch = regs.shape
     num_el, rows = opcode.shape
@@ -145,6 +148,7 @@ def optable_run(
         out_shape=jax.ShapeDtypeStruct((num_regs, padded), jnp.uint32),
         scratch_shapes=[pltpu.VMEM((rows, bb), jnp.uint32)],
         interpret=interpret,
+        name=name or f"optable_e0_{num_el}",
     )(tables, regs)
     return out[:, :batch] if pad else out
 
@@ -170,7 +174,8 @@ def optable_run_segmented(
     used)`` element ranges.  Bit-identical to one :func:`optable_run` over
     the whole table with the union used-set — but each segment's kernel
     specializes ``alu_variants`` to that segment's opcodes, collapsing the
-    per-row where-select chain to (usually) a single expression.
+    per-row where-select chain to (usually) a single expression.  Each
+    segment's kernel is named ``optable_e<start>_<stop>``.
     """
     for start, stop, used in runs:
         regs = optable_run(
@@ -180,5 +185,6 @@ def optable_run_segmented(
             imm0[start:stop], imm1[start:stop],
             mask[start:stop], first_write[start:stop],
             used=used, block_b=block_b, interpret=interpret,
+            name=f"optable_e{start}_{stop}",
         )
     return regs

@@ -10,7 +10,13 @@ the global registry), and three switched capabilities:
   per-tenant queue delay, drops/defers, jit/table cache hits;
 * a **span tracer** (``obs.tracing``): nested context-manager spans
   (``stream > chunk > hop > execute``) with explicit ``compile`` vs
-  ``execute`` categories, exporting Chrome Trace Event JSON;
+  ``execute`` categories, exporting Chrome Trace Event JSON; while a JAX
+  profiler session collects, every span is also written into the
+  profiler's own trace (``jax.profiler.TraceAnnotation``), on the clock
+  the device trace shares, whether or not the switch is on;
+* a **lowering counter**: while the switch is on, every program JAX
+  lowers counts into ``jax.lowerings_total`` and
+  ``jax.lowering_seconds_total``, labelled with the innermost open span;
 * **exporters** (``obs.export``): metrics JSONL, Prometheus-style text,
   chrome trace — rendered human-readable by ``tools/obs_report.py``.
 
@@ -34,20 +40,24 @@ export::
 
 Invariants:
 
-* **Disabled means no-op** — with the switch off, :func:`span` returns a
-  shared null context manager and instrumented code skips all metric
-  work; the instrumented paths are bit-exact with uninstrumented code in
-  *both* states (observability never touches data), and the disabled-path
-  overhead is bounded by test and benchmark (< 5%).
+* **Disabled means no-op** — with the switch off and no profiler
+  collecting, :func:`span` returns a shared null context manager and
+  instrumented code skips all metric work; the instrumented paths are
+  bit-exact with uninstrumented code in *both* states (observability
+  never touches data), and the disabled-path overhead is bounded by test
+  and benchmark (< 5%).
 * **One global state** — helpers address a single process-wide registry +
   tracer pair, so instrumentation at any layer lands in one export.
   :func:`enable`'s ``reset=True`` starts a clean capture.
 * **Import-light** — this package imports only stdlib + numpy; dataplane
-  modules can instrument without import cycles.
+  modules can instrument without import cycles.  JAX is looked up only
+  once the process has imported it (no profiler can run before), and
+  imported by :func:`enable` for the lowering listener.
 """
 from __future__ import annotations
 
 import os
+import sys
 
 from repro.obs import export as _export
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
@@ -102,6 +112,73 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_TRACE_ME = None       # jax.profiler.TraceAnnotation, once JAX is imported
+_listening = False      # _count_lowering is registered with jax.monitoring
+
+
+def _profiler_collecting() -> bool:
+    """Is a JAX profiler session collecting host events?"""
+    global _TRACE_ME
+    if _TRACE_ME is None:
+        if "jax" not in sys.modules:  # no profiler runs before JAX does
+            return False
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ME = TraceAnnotation
+    return _TRACE_ME.is_enabled()
+
+
+class _ProfiledSpan:
+    """A profiler annotation around the tracer's span (``None`` while the
+    switch is off): one phase, on both clocks."""
+
+    __slots__ = ("_annotation", "_span")
+
+    def __init__(self, annotation, span):
+        self._annotation = annotation
+        self._span = span
+
+    def __enter__(self) -> "_ProfiledSpan":
+        self._annotation.__enter__()
+        if self._span is not None:
+            self._span.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        try:
+            if self._span is not None:
+                self._span.__exit__(exc_type, exc, tb)
+        finally:
+            self._annotation.__exit__(exc_type, exc, tb)
+        return False
+
+
+def _count_lowering(event: str, duration: float, **kwargs) -> None:
+    """``jax.monitoring`` listener: a lowering, under the calling thread's
+    innermost open span."""
+    if event != LOWERING_EVENT:
+        return
+    stack = _tracer._stack()
+    where = stack[-1] if stack else "none"
+    _registry.counter("jax.lowerings_total", span=where).inc()
+    _registry.counter("jax.lowering_seconds_total", span=where).inc(duration)
+
+
+def _listen_for_lowerings(on: bool) -> None:
+    global _listening
+    if on == _listening:
+        return
+    try:
+        import jax.monitoring as monitoring
+    except ImportError:
+        return
+    if on:
+        monitoring.register_event_duration_secs_listener(_count_lowering)
+    else:
+        monitoring.unregister_event_duration_listener(_count_lowering)
+    _listening = on
+
 
 def enabled() -> bool:
     """Is the global observability switch on?"""
@@ -109,18 +186,21 @@ def enabled() -> bool:
 
 
 def enable(*, reset: bool = False) -> None:
-    """Turn observability on (``reset=True`` starts a clean capture)."""
+    """Turn observability on (``reset=True`` starts a clean capture) and
+    start counting lowerings per span."""
     global _enabled
     if reset:
         globals()["_registry"] = MetricsRegistry()
         _tracer.reset()
     _enabled = True
+    _listen_for_lowerings(True)
 
 
 def disable() -> None:
     """Turn observability off (captured state is kept for export)."""
     global _enabled
     _enabled = False
+    _listen_for_lowerings(False)
 
 
 def reset() -> None:
@@ -152,7 +232,14 @@ def tracer() -> Tracer:
 
 
 def span(name: str, cat: str = "span", **args):
-    """A timed span when enabled, a shared no-op otherwise."""
+    """A timed span when enabled, written into the profiler's trace (name
+    and args) while a JAX profiler session collects; a shared no-op when
+    neither holds."""
+    if _profiler_collecting():
+        return _ProfiledSpan(
+            _TRACE_ME(name, **args),
+            _tracer.span(name, cat, **args) if _enabled else None,
+        )
     if not _enabled:
         return _NULL_SPAN
     return _tracer.span(name, cat, **args)

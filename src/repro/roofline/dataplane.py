@@ -22,10 +22,12 @@ and ``fraction = measured_pps / roofline_pps`` is the utilization number
 the CI gate tracks (``dataplane_packed_roofline_frac``).
 
 Probes are cached per (fingerprint, path, shape): lowering + HLO analysis
-costs milliseconds but not nothing, and the executor hooks run it at most
-once per compiled executable — in the warmup window, never on the steady
-hot path, and only when ``repro.obs`` is enabled (``record`` is the
-fail-soft entry point the executor/fleet/serving hooks call).
+costs milliseconds but not nothing.  ``serving.engine.FleetEngine`` runs
+the fleet probe at most once per compiled executable — in its warmup
+window, never on the steady hot path, and only when ``repro.obs`` is
+enabled — and reports it in ``health()``; ``record`` publishes a probe's
+gauges.  The served loops (``executor.execute_stream``,
+``fleet.execute_fleet``) run no probe.
 
 Everything JAX-facing is imported lazily so this module stays importable
 (and the analyzer usable on saved HLO text) without touching the
@@ -206,9 +208,8 @@ def record(rf: DataplaneRoofline, measured_pps: float | None = None) -> None:
     """Publish a probe's costs (and utilization, when a measured rate is
     known) as ``roofline.*`` gauges in the global obs registry.
 
-    The hook the executor/fleet/serving paths call from their warmup
-    windows; a no-op when observability is off, so the disabled hot path
-    stays untouched.
+    ``FleetEngine`` calls it after a serve; a no-op when observability is
+    off, so the disabled hot path stays untouched.
     """
     from repro import obs
 

@@ -210,6 +210,43 @@ class FleetServeResult:
         return busy / self.wall_seconds if self.wall_seconds > 0 else 1.0
 
 
+def _count_probe_error() -> None:
+    """A roofline probe or gauge publication failed: the serve goes on, and
+    ``roofline.probe_errors_total`` says so."""
+    obs.registry().counter("roofline.probe_errors_total").inc()
+
+
+def _probe_fleet_roofline(lowered, backend, n_streams, chunk, plan):
+    """Fail-soft ``roofline.dataplane`` probe of the vmapped fleet dispatch
+    that ``health()`` reports — obs-only bookkeeping, never allowed to
+    affect an execution path."""
+    try:
+        from repro.roofline import dataplane as _roofline_dp
+
+        return _roofline_dp.probe_fleet(
+            lowered,
+            backend=backend,
+            streams=n_streams,
+            chunk=chunk,
+            interpret=plan.interpret,
+            scan_hops=bool(plan.scan_hops),
+            devices=plan.devices,
+        )
+    except Exception:  # noqa: BLE001 - observation must not break runs
+        _count_probe_error()
+        return None
+
+
+def _record_roofline(roofline, measured_pps):
+    """Fail-soft gauge publication for a probe (see ``_probe_fleet_roofline``)."""
+    try:
+        from repro.roofline import dataplane as _roofline_dp
+
+        _roofline_dp.record(roofline, measured_pps=measured_pps)
+    except Exception:  # noqa: BLE001 - observation must not break runs
+        _count_probe_error()
+
+
 @dataclasses.dataclass(frozen=True)
 class FleetHealth:
     """Live ``FleetEngine`` snapshot as of an explicit ``now``.
@@ -451,7 +488,7 @@ class FleetEngine:
                         self.fn(dev).block_until_ready()
                         warmup = time.perf_counter() - w0
                     if obs.enabled():  # cost the compiled dispatch, once
-                        self._roofline = _fleet._probe_fleet_roofline(
+                        self._roofline = _probe_fleet_roofline(
                             self.lowered, self.backend, n_streams,
                             self.chunk, self.plan,
                         )
@@ -487,9 +524,7 @@ class FleetEngine:
         if obs.enabled() and wall > 0:
             obs.registry().gauge("fleet.serve_pps").set(total / wall)
             if self._roofline is not None:
-                _fleet._executor._record_roofline(
-                    self._roofline, total / wall
-                )
+                _record_roofline(self._roofline, total / wall)
         outputs = None
         if collected is not None:
             outputs = [

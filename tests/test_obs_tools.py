@@ -124,6 +124,29 @@ def test_report_hardware_utilization_section(tmp_path, capsys):
     assert "dataplane.stream_pps" in out
 
 
+def test_report_lowerings_by_span_section(tmp_path, capsys):
+    _write_metrics(
+        tmp_path / "run_metrics.jsonl",
+        [
+            {"name": "jax.lowerings_total", "type": "counter", "value": 3,
+             "labels": {"span": "dispatch"}},
+            {"name": "jax.lowering_seconds_total", "type": "counter",
+             "value": 0.25, "labels": {"span": "dispatch"}},
+            {"name": "jax.lowerings_total", "type": "counter", "value": 1,
+             "labels": {"span": "none"}},
+            {"name": "dataplane.chunks_total", "type": "counter", "value": 7},
+        ],
+    )
+    assert obs_report.main([str(tmp_path)]) == 0
+    out = capsys.readouterr().out
+    section = out.split("== lowerings by span")[1].split("==\n")[1].split("\n\n")[0]
+    rows = [line.split() for line in section.splitlines()[1:]]
+    assert rows[0][:2] == ["dispatch", "3"] and rows[1][:2] == ["none", "1"]
+    # grouped, not repeated in the generic counter dump
+    assert "jax.lowerings_total" not in out
+    assert "dataplane.chunks_total = 7" in out
+
+
 # --------------------------------------------------------------- obs_diff
 
 def _export_dir(tmp_path, name, pps, events=True):

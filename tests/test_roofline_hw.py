@@ -8,9 +8,10 @@ import pytest
 
 from repro import obs
 from repro.core import bnn, compile_bnn
-from repro.dataplane import executor, lower_program
+from repro.dataplane import ExecutionPlan, lower_program
 from repro.roofline import dataplane as roofline_dp
 from repro.roofline import hw
+from repro.serving.engine import FleetEngine
 
 
 def _device(platform: str, kind: str):
@@ -75,17 +76,22 @@ def test_no_peaks_means_no_bound(obs_on):
 
 
 def test_failed_probe_is_counted_and_run_stays_exact(obs_on, monkeypatch):
+    """``FleetEngine`` is the one path that still probes: a probe that
+    fails is counted, the serve's outputs stay exact, and ``health()``
+    reports no bound."""
     def refuse(device):
         raise hw.UnknownDeviceError("no peaks")
 
     monkeypatch.setattr(hw, "device_peaks", refuse)
     params = bnn.init_params(bnn.BnnSpec((16, 8, 4)), jax.random.PRNGKey(3))
     lp = lower_program(compile_bnn([np.asarray(w) for w in params]))
-    x = np.random.default_rng(0).integers(0, 2, (300, 16)).astype(np.int32)
-    res = executor.execute_stream(
-        lp, [x], backend="jnp", chunk_size=128, collect=True
+    rng = np.random.default_rng(0)
+    streams = [rng.integers(0, 2, (n, 16)).astype(np.int32) for n in (300, 170)]
+    eng = FleetEngine(
+        lp, plan=ExecutionPlan(backend="jnp", fleet=2, chunk_size=128)
     )
-    np.testing.assert_array_equal(
-        res.outputs, np.asarray(bnn.forward(params, x))
-    )
+    res = eng.serve(streams, collect=True)
+    for got, x in zip(res.outputs, streams):
+        np.testing.assert_array_equal(got, np.asarray(bnn.forward(params, x)))
     assert obs_on.counter("roofline.probe_errors_total").value == 1
+    assert eng.health().roofline_pps_bound is None

@@ -9,6 +9,9 @@ and a Chrome Trace Event JSON — and prints the operator's view of a run:
 * **top spans** — where the time went, by span name;
 * **per-tenant table** — packets / served / dropped / deferred and queue
   delay p50/p99 per tenant (from the ``mt.*`` metric family);
+* **lowerings by span** — ``jax.lowerings_total`` and
+  ``jax.lowering_seconds_total`` per innermost open span: which step
+  recompiled, and what it cost;
 * **hardware utilization** — the ``roofline.*`` gauge family grouped per
   compiled path (``packed``, ``jnp``, ``fleetN:...``): analytic packets/s
   bound, measured fraction of it, and bytes per packet
@@ -32,6 +35,9 @@ import glob
 import json
 import os
 import sys
+
+
+LOWERING_COUNTERS = ("jax.lowerings_total", "jax.lowering_seconds_total")
 
 
 def load_metrics(path: str) -> list[dict]:
@@ -174,6 +180,23 @@ def tenant_table(metrics: list[dict]) -> list[dict]:
     return [tenants[k] for k in sorted(tenants)]
 
 
+def lowering_table(metrics: list[dict]) -> list[tuple[str, int, float]]:
+    """(span, lowerings, seconds) from the ``jax.lowering*`` counters
+    (``repro.obs``'s lowering listener), most seconds first."""
+    rows: dict[str, list] = {}
+    for row in metrics:
+        name = row["name"]
+        if row.get("type") != "counter" or name not in LOWERING_COUNTERS:
+            continue
+        span = (row.get("labels") or {}).get("span", "?")
+        r = rows.setdefault(span, [span, 0, 0.0])
+        if name == "jax.lowerings_total":
+            r[1] = int(row.get("value", 0))
+        else:
+            r[2] = float(row.get("value", 0.0))
+    return sorted((tuple(r) for r in rows.values()), key=lambda r: -r[2])
+
+
 def roofline_table(metrics: list[dict]) -> list[dict]:
     """Per-path rollup of the ``roofline.*`` gauge family
     (``repro.roofline.dataplane.record``)."""
@@ -231,6 +254,14 @@ def render(metrics: list[dict], events: list[dict]) -> str:
             )
         out("")
 
+    lowerings = lowering_table(metrics)
+    if lowerings:
+        out("== lowerings by span (jax.lowering*) ==")
+        out(f"  {'span':<28} {'count':>6} {'total':>10}")
+        for span, n, sec in lowerings:
+            out(f"  {span:<28} {n:>6} {_fmt_s(sec):>10}")
+        out("")
+
     roofline = roofline_table(metrics)
     if roofline:
         out("== hardware utilization (roofline.*) ==")
@@ -250,7 +281,10 @@ def render(metrics: list[dict], events: list[dict]) -> str:
             )
         out("")
 
-    counters = [m for m in metrics if m["type"] == "counter"]
+    counters = [
+        m for m in metrics
+        if m["type"] == "counter" and m["name"] not in LOWERING_COUNTERS
+    ]
     gauges = [
         m for m in metrics
         if m["type"] == "gauge" and not m["name"].startswith("roofline.")
