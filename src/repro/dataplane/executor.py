@@ -857,6 +857,56 @@ def routed_packed_stacked_fn(lowereds: tuple):
     return fn
 
 
+def _chunk_body(lp: LoweredProgram, backend: str, interpret, scan_hops: bool):
+    """Traceable (chunk, bits) {0,1} -> (chunk, out_bits) int32 for one
+    stream: parse -> :func:`run_hop` -> deparse, or the packed function.
+    :func:`_run_chunk` jits it; ``fleet.fleet_fn`` vmaps it over streams."""
+    if backend == "packed":
+        return _packed_scan_fn(lp) if scan_hops else _packed_fn(lp)
+    t = _device_tables(lp)
+    in_slot, in_shift, out_slot, out_shift = t.io
+
+    def run(block: jax.Array) -> jax.Array:
+        regs = parse_packets(block, in_slot, in_shift, num_regs=lp.num_regs)
+        regs = run_hop(lp, regs, backend=backend, interpret=interpret)
+        return deparse_regs(regs, out_slot, out_shift)
+
+    return run
+
+
+_CHUNK_CACHE: dict[tuple, object] = {}
+
+
+def _chunk_fn(
+    lp: LoweredProgram, backend: str, interpret, scan_hops: bool = False
+):
+    """:func:`_chunk_body` under one ``jax.jit`` (module
+    ``jit_stream_chunk``), cached per (program fingerprint, backend,
+    interpret, scan_hops): a chunk shape lowers once, on its first call."""
+    backend = resolve_backend(backend)
+    key = (
+        lp.fingerprint(),
+        backend,
+        None if interpret is None else bool(interpret),
+        bool(scan_hops),
+    )
+    fn = _CHUNK_CACHE.get(key)
+    if obs.enabled():
+        obs.registry().counter(
+            "dataplane.chunk_fn_cache_hits_total"
+            if fn is not None
+            else "dataplane.chunk_fn_cache_misses_total"
+        ).inc()
+    if fn is None:
+        body = _chunk_body(lp, backend, interpret, scan_hops)
+
+        def stream_chunk(block: jax.Array) -> jax.Array:
+            return body(block)
+
+        fn = _CHUNK_CACHE[key] = jax.jit(stream_chunk)
+    return fn
+
+
 def _run_chunk(
     lp: LoweredProgram,
     packets: jax.Array,
@@ -864,14 +914,8 @@ def _run_chunk(
     interpret: bool | None,
     scan_hops: bool = False,
 ) -> jax.Array:
-    if backend == "packed":
-        fn = _packed_scan_fn(lp) if scan_hops else _packed_fn(lp)
-        return fn(packets)
-    t = _device_tables(lp)
-    in_slot, in_shift, out_slot, out_shift = t.io
-    regs = parse_packets(packets, in_slot, in_shift, num_regs=lp.num_regs)
-    regs = run_hop(lp, regs, backend=backend, interpret=interpret)
-    return deparse_regs(regs, out_slot, out_shift)
+    """One chunk through the program's one compiled dispatch."""
+    return _chunk_fn(lp, backend, interpret, scan_hops)(packets)
 
 
 # ---------------------------------------------------------------------------

@@ -55,28 +55,6 @@ DEFAULT_STREAM_CHUNK = 1 << 12
 _FLEET_CACHE: dict[tuple, object] = {}
 
 
-def _chunk_fn(lp: LoweredProgram, backend: str, interpret, scan_hops: bool):
-    """Traceable (chunk, bits) {0,1} -> (chunk, out_bits) int32 for one
-    stream — the body :func:`fleet_fn` vmaps over the stream axis."""
-    if backend == "packed":
-        return (
-            _executor._packed_scan_fn(lp)
-            if scan_hops
-            else _executor._packed_fn(lp)
-        )
-    t = _executor._device_tables(lp)
-    in_slot, in_shift, out_slot, out_shift = t.io
-
-    def run(block: jax.Array) -> jax.Array:
-        regs = _executor.parse_packets(
-            block, in_slot, in_shift, num_regs=lp.num_regs
-        )
-        regs = _executor.run_hop(lp, regs, backend=backend, interpret=interpret)
-        return _executor.deparse_regs(regs, out_slot, out_shift)
-
-    return run
-
-
 def fleet_fn(
     lowered: LoweredProgram,
     *,
@@ -104,7 +82,9 @@ def fleet_fn(
     fn = _FLEET_CACHE.get(key)
     if fn is not None:
         return fn
-    batched = jax.vmap(_chunk_fn(lowered, backend, interpret, scan_hops))
+    batched = jax.vmap(
+        _executor._chunk_body(lowered, backend, interpret, scan_hops)
+    )
     if devices is not None:
         batched = _sharding.shard_streams(
             batched, _sharding.fleet_mesh(devices)

@@ -146,9 +146,9 @@ def probe_stream(
     """Roofline for one ``executor._run_chunk`` dispatch at ``chunk``
     packets — the executable ``execute`` / ``execute_stream`` runs.
 
-    Wraps the whole chunk path (parse -> hop -> deparse, which on the
-    op-table backends is a *composition* of jitted pieces) in one jit and
-    AOT-lowers it, so the analyzed HLO is the fused dispatch, not a part.
+    AOT-lowers the chunk path (parse -> hop -> deparse, the one cached jit
+    ``_run_chunk`` dispatches) at ``chunk`` packets, so the analyzed HLO is
+    the whole dispatch, not a part.
     """
     path = backend + ("+scan" if scan_hops else "")
     key = (lowered.fingerprint(), path, chunk, 1, interpret)
@@ -160,11 +160,7 @@ def probe_stream(
 
     from repro.dataplane import executor as _executor
 
-    fn = jax.jit(
-        lambda p: _executor._run_chunk(
-            lowered, p, backend, interpret, scan_hops
-        )
-    )
+    fn = _executor._chunk_fn(lowered, backend, interpret, scan_hops)
     spec = jax.ShapeDtypeStruct((chunk, lowered.input_bits), jnp.int32)
     costs = analyze(fn.lower(spec).compile().as_text())
     return _build(key, path, lowered, chunk, 1, costs)

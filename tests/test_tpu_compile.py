@@ -102,6 +102,21 @@ def test_packed_dispatch_compiles_for_v5e(one_chip, name, scan):
     _compiled_text(fn, packets)
 
 
+@pytest.mark.parametrize("backend", ["pallas", "packed"])
+def test_stream_chunk_compiles_for_v5e(one_chip, backend):
+    """The stream path's one cached dispatch: parse, every opcode run's
+    kernel and deparse in the single module ``jit_stream_chunk``."""
+    lp = _lowered("HEADLINE")
+    packets = jax.ShapeDtypeStruct(
+        (CHUNK, lp.input_bits), jnp.int32, sharding=one_chip
+    )
+    fn = executor._chunk_fn(lp, backend, False)
+    text = fn.lower(packets).compile().as_text()
+    assert text.startswith("HloModule jit_stream_chunk")
+    kernels = len(lp.opcode_runs()) if backend == "pallas" else 0
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+
+
 def test_routed_merged_dispatch_compiles_for_v5e(one_chip):
     shapes = ((16, 8, 4), (32, 16), (8, 12, 6))
     progs = [
@@ -144,7 +159,7 @@ def test_sharded_fleet_compiles_for_four_v5e_chips(topo, backend):
     lp = _lowered("HEADLINE")
     mesh = Mesh(np.asarray(topo.devices[:4]), ("fleet",))
     fn = sharding.shard_streams(
-        jax.vmap(fleet._chunk_fn(lp, backend, False, False)), mesh
+        jax.vmap(executor._chunk_body(lp, backend, False, False)), mesh
     )
     blocks = jax.ShapeDtypeStruct(
         (16, fleet.DEFAULT_STREAM_CHUNK, lp.input_bits), jnp.int32,
