@@ -27,6 +27,21 @@ incoming PHV before writing, so a stage's outputs may land in containers its
 inputs occupied).  This reproduces the paper's bound exactly: the duplication
 stage holds ``2*P*N`` live bits, hence max activation length 2048 on a 512B
 PHV (4096 with native POPCNT).
+
+**Recirculation passes** (paper §2-3).  Where that one-pass schedule breaks
+a budget (an element over the ALU lanes, or the PHV over its bits), the
+network compiles into passes instead, each within ``chip.num_elements``
+elements, the lanes and the PHV on its own.  A pass runs one group of ``P``
+neurons over one fan-in slice: replication, XNOR and duplication, and the
+POPCNT tree of that slice, then either an ADD into the group's partial
+count or, on the last slice, the ADD and SIGN.  The first layer's slice is
+re-parsed from the packet at the start of its pass; everything else a later
+pass needs rides in the recirculation header (the carried PHV fields): the
+group's partial counts, the layer's finished sign bits, and a later layer's
+input, the previous layer's sign bits folded into words at its first pass.
+Slice and group sizes are chosen per layer for the fewest passes, each
+candidate emitted apart to check its budgets.  Networks that compile in one
+pass compile exactly as before.
 """
 from __future__ import annotations
 
@@ -45,6 +60,7 @@ from repro.core.pipeline import (
     LayerPlan,
     Op,
     OpCode,
+    PassPlan,
     PipelineProgram,
     ProgramConstraintError,
 )
@@ -68,6 +84,18 @@ def _chunk_layout(n_bits: int) -> list[tuple[int, int]]:
         w = min(32, n_bits - off)
         out.append((off, w))
         off += w
+    return out
+
+
+def _split(n: int, parts: int) -> list[tuple[int, int]]:
+    """``[0, n)`` cut into ``parts`` contiguous ranges, sizes differing by at
+    most one, the larger first."""
+    base, extra = divmod(n, parts)
+    out, lo = [], 0
+    for i in range(parts):
+        hi = lo + base + (1 if i < extra else 0)
+        out.append((lo, hi))
+        lo = hi
     return out
 
 
@@ -136,7 +164,15 @@ class Compiler:
             if not np.isin(w, (0, 1)).all():
                 raise ValueError("weights must be {0,1} bit matrices")
         self._thresholds = _normalize_thresholds(weights, thresholds)
+        try:
+            return self._compile_one_pass(weights)
+        except ProgramConstraintError:
+            pass
+        passes = Compiler(self.chip)
+        passes._thresholds = self._thresholds
+        return passes._compile_passes(weights)
 
+    def _compile_one_pass(self, weights: list[np.ndarray]) -> PipelineProgram:
         n_in = weights[0].shape[1]
         in_refs = [
             _FieldRef(self.alloc.alloc(f"x[{off}:{off + w}]", w), off, w)
@@ -217,6 +253,12 @@ class Compiler:
             out_refs += self._emit_group(
                 li, w[done : done + p], in_refs, done, last_group
             )
+            if self.alloc.peak_live_bits > self.chip.phv_bits:
+                # validate() would refuse it; stop emitting now
+                raise ProgramConstraintError(
+                    f"peak PHV usage {self.alloc.peak_live_bits}b exceeds "
+                    f"{self.chip.phv_bits}b"
+                )
             done += p
             groups += 1
 
@@ -244,9 +286,61 @@ class Compiler:
         p, n_in = w_group.shape
         a = self.alloc
         name = f"L{li}g{neuron_base}"
+        counts = self._emit_counts(name, w_group, in_refs, last_group)
+        signs = self._emit_sign(li, name, counts, neuron_base)
+
+        # ---- step 5: folding -------------------------------------------------
+        if p == 1:
+            return [_FieldRef(signs[0], neuron_base, 1)]
+        return self._emit_fold(name, signs, neuron_base)
+
+    def _emit_sign(
+        self, li: int, name: str, counts: list, neuron_base: int
+    ) -> list:
+        """Step 4, SIGN: one bit per count.  Default: popcount >=
+        ceil(n_in/2)  <=>  sum >= 0; learned per-neuron thresholds
+        (compile(..., thresholds=...)) override per SIGN imm."""
+        thr_vec = self._thresholds[li]
+        a = self.alloc
+        a.free(counts)  # sign bits overlay the consumed count containers
+        el = self._element("sign")
+        signs = []
+        for j, count in enumerate(counts):
+            dst = a.alloc(f"{name}.s{j}", 1)
+            thr = int(thr_vec[neuron_base + j])
+            el.add(Op(OpCode.GE_IMM, dst, (count,), (thr,)))
+            signs.append(dst)
+        return signs
+
+    def _emit_fold(
+        self, name: str, signs: list, base: int = 0
+    ) -> list[_FieldRef]:
+        """Step 5, folding: deposit sign bits (neurons ``base`` on) into
+        words of up to 32."""
+        a = self.alloc
+        a.free(signs)
+        el = self._element("folding")
+        out: list[_FieldRef] = []
+        for off in range(0, len(signs), 32):
+            chunk = signs[off : off + 32]
+            dst = a.alloc(f"{name}.y{off}", len(chunk))
+            el.add(Op(OpCode.FOLD, dst, tuple(chunk)))
+            out.append(_FieldRef(dst, base + off, len(chunk)))
+        return out
+
+    def _emit_counts(
+        self, name: str, w_group: np.ndarray, in_refs: list[_FieldRef],
+        free_input: bool,
+    ) -> list:
+        """Steps 1-3 for the ``p`` neurons of ``w_group`` (full-width rows:
+        ``in_refs`` index them by their offsets) over ``in_refs``; returns
+        the ``p`` count fields.  ``free_input``: this group is the input's
+        last reader, so replication may overlay it."""
+        p = w_group.shape[0]
+        a = self.alloc
 
         # ---- step 1: replication ------------------------------------------
-        if last_group:
+        if free_input:
             a.free(r.field for r in in_refs)  # overlay: outputs may reuse input
         el = self._element("replication")
         repl = [
@@ -284,35 +378,8 @@ class Compiler:
 
         # ---- step 3: POPCNT ------------------------------------------------
         if self.chip.native_popcnt:
-            counts = self._emit_popcnt_native(name, p, xn)
-        else:
-            counts = self._emit_popcnt_hakmem(name, p, xn, in_refs)
-
-        # ---- step 4: SIGN ---------------------------------------------------
-        # Default: popcount >= ceil(n_in/2)  <=>  sum >= 0; learned per-neuron
-        # thresholds (compile(..., thresholds=...)) override per SIGN imm.
-        thr_vec = self._thresholds[li]
-        a.free(counts)  # sign bits overlay the consumed count containers
-        el = self._element("sign")
-        signs = []
-        for j in range(p):
-            dst = a.alloc(f"{name}.s{j}", 1)
-            thr = int(thr_vec[neuron_base + j])
-            el.add(Op(OpCode.GE_IMM, dst, (counts[j],), (thr,)))
-            signs.append(dst)
-
-        # ---- step 5: folding -------------------------------------------------
-        if p == 1:
-            return [_FieldRef(signs[0], neuron_base, 1)]
-        a.free(signs)
-        el = self._element("folding")
-        out: list[_FieldRef] = []
-        for off in range(0, p, 32):
-            chunk = signs[off : off + 32]
-            dst = a.alloc(f"{name}.y{off}", len(chunk))
-            el.add(Op(OpCode.FOLD, dst, tuple(chunk)))
-            out.append(_FieldRef(dst, neuron_base + off, len(chunk)))
-        return out
+            return self._emit_popcnt_native(name, p, xn)
+        return self._emit_popcnt_hakmem(name, p, xn, in_refs)
 
     def _emit_popcnt_hakmem(
         self, name: str, p: int, xn, in_refs: list[_FieldRef]
@@ -488,6 +555,217 @@ class Compiler:
                 new.append(nrow)
             cur = new
         return [row[0].field for row in cur]
+
+    # -- recirculation passes -------------------------------------------------
+
+    def _compile_passes(self, weights: list[np.ndarray]) -> PipelineProgram:
+        """Compile into recirculation passes (module docstring): one neuron
+        group over one fan-in slice a pass."""
+        a = self.alloc
+        n_in = weights[0].shape[1]
+        self._packet_words = _chunk_layout(n_in)
+        packet = [a.detached(f"pkt[{o}:{o + w}]", w) for o, w in self._packet_words]
+        self._pass_plans: list[PassPlan] = []
+        acts: list = []
+        for li, w in enumerate(weights):
+            if li and w.shape[1] != len(acts):
+                raise ValueError(
+                    f"layer {li}: weight fan-in {w.shape[1]} != activation "
+                    f"bits {len(acts)}"
+                )
+            acts = self._emit_layer_passes(li, w, acts)
+        prog = PipelineProgram(
+            chip=self.chip,
+            elements=self.elements,
+            num_fields=a.num_fields_created,
+            input_fields=packet,
+            input_bits=n_in,
+            output_fields=acts,
+            output_bits=len(acts),
+            layer_plans=self.layer_plans,
+            peak_phv_bits=max(pp.peak_phv_bits for pp in self._pass_plans),
+            packed_layers=tuple(
+                (w.astype(np.uint8), thr)
+                for w, thr in zip(weights, self._thresholds)
+            ),
+            pass_plans=tuple(self._pass_plans),
+        )
+        prog.validate()
+        return prog
+
+    def _emit_layer_passes(self, li: int, w: np.ndarray, prev: list) -> list:
+        """One layer as passes; ``prev`` holds the previous layer's sign
+        fields (carried, neuron order).  Returns this layer's sign fields."""
+        n_out, n_in = w.shape
+        k, p = self._pass_shape(li, n_in, n_out)
+        slices = _split(len(_chunk_layout(n_in)), k)
+        groups = _split(n_out, -(-n_out // p))
+        el_start = len(self.elements)
+        words = None
+        signs: list = []
+        for gi, (g0, g1) in enumerate(groups):
+            acc = None
+            for si, (s0, s1) in enumerate(slices):
+                self._begin_pass()
+                if li == 0:
+                    refs, free = self._parse_words(s0, s1), True
+                else:
+                    if words is None:
+                        words = self._emit_fold(f"L{li}", prev)
+                    refs, free = words[s0:s1], gi == len(groups) - 1
+                last = si == len(slices) - 1
+                out = self._emit_slice_group(
+                    li, w[g0:g1], refs, g0, f"L{li}g{g0}s{s0}", free, acc, last
+                )
+                if last:
+                    signs += out
+                else:
+                    acc = out
+                self._end_pass()
+        self.layer_plans.append(
+            LayerPlan(
+                layer_index=li,
+                n_in=n_in,
+                n_out=n_out,
+                parallel=groups[0][1],
+                groups=len(groups),
+                elements_per_group=(len(self.elements) - el_start) // len(groups),
+                element_range=(el_start, len(self.elements)),
+            )
+        )
+        return signs
+
+    def _begin_pass(self) -> None:
+        self._pass_start = len(self.elements)
+        self._pass_carried = tuple(self.alloc.iter_live())
+        self._pass_parsed: list = []
+        self.alloc.reset_peak()
+
+    def _end_pass(self) -> None:
+        self._pass_plans.append(
+            PassPlan(
+                element_range=(self._pass_start, len(self.elements)),
+                parsed=tuple(self._pass_parsed),
+                carried=self._pass_carried,
+                peak_phv_bits=self.alloc.peak_live_bits,
+            )
+        )
+
+    def _parse_words(self, w0: int, w1: int) -> list[_FieldRef]:
+        """The parser's step at a pass's start: input words ``[w0, w1)``
+        extracted again from the packet into fresh PHV fields."""
+        out = []
+        for off, width in self._packet_words[w0:w1]:
+            f = self.alloc.alloc(f"x[{off}:{off + width}]", width)
+            self._pass_parsed.append((f, off))
+            out.append(_FieldRef(f, off, width))
+        return out
+
+    def _emit_slice_group(
+        self, li: int, w_group: np.ndarray, refs: list[_FieldRef],
+        neuron_base: int, name: str, free_input: bool, acc: list | None,
+        last: bool,
+    ) -> list:
+        """One pass's work: the group's counts over the slice ``refs``, added
+        into its carried partial counts ``acc``; on the ``last`` slice, the
+        SIGN.  Returns the partial counts, or the sign bits."""
+        a = self.alloc
+        counts = self._emit_counts(name, w_group, refs, free_input)
+        if acc is not None:
+            a.free(acc + counts)
+            el = self._element("accumulate")
+            width = w_group.shape[1].bit_length()  # a count never passes n_in
+            summed = []
+            for j, (x, y) in enumerate(zip(acc, counts)):
+                dst = a.alloc(f"{name}.acc{j}", width)
+                el.add(Op(OpCode.ADD, dst, (x, y)))
+                summed.append(dst)
+            counts = summed
+        if not last:
+            return counts
+        return self._emit_sign(li, name, counts, neuron_base)
+
+    def _pass_shape(self, li: int, n_in: int, n_out: int) -> tuple[int, int]:
+        """``(k, p)``: ``k`` fan-in slices and groups of up to ``p`` neurons,
+        the fewest passes (then elements) whose worst pass fits the chip."""
+        n_words = len(_chunk_layout(n_in))
+        ks = sorted({min(1 << i, n_words) for i in range(n_words.bit_length() + 1)})
+        copies = 1 if self.chip.native_popcnt else 2
+        best, reason = None, ""
+        for k in ks:
+            # The XNOR step alone writes copies * p * slice bits: a bound on
+            # p before any trial.
+            dup = copies * -(-n_in // k)
+            cap = min(self.chip.max_parallel_ops * 32, self.chip.phv_bits) // dup
+            lo, hi, fit = 1, max(1, min(n_out, cap)), None
+            while lo <= hi:  # the budgets only tighten as p grows
+                mid = (lo + hi) // 2
+                elements, why = self._trial(li, n_in, n_out, k, mid)
+                if elements is None:
+                    reason, hi = why, mid - 1
+                else:
+                    fit, lo = (mid, elements), mid + 1
+            if fit is not None:
+                key = (-(-n_out // fit[0]) * k, fit[1])
+                if best is None or key < best[0]:
+                    best = (key, k, fit[0])
+        if best is None:
+            raise ProgramConstraintError(
+                f"layer {li} ({n_in}->{n_out}): no pass holds one neuron over "
+                f"its narrowest fan-in slice: {reason}"
+            )
+        return best[1], best[2]
+
+    def _trial(
+        self, li: int, n_in: int, n_out: int, k: int, p: int
+    ) -> tuple[int | None, str]:
+        """Emit a layer's worst passes apart, for ``k`` slices and ``p``
+        neurons a group: the first slice's (with the fold of the layer's
+        input, for a later layer) and the last slice's (with the partial
+        counts carried in, the ADD and the SIGN), each under the signs of
+        every other group.  Returns (the most elements a pass took, "") if
+        every budget holds, else (None, the budget that broke)."""
+        t = Compiler(self.chip)
+        t._thresholds = self._thresholds
+        t._packet_words = _chunk_layout(n_in)
+        t._pass_plans = []
+        slices = _split(len(t._packet_words), k)
+        groups = -(-n_out // p)
+        w = np.zeros((p, n_in), np.int64)
+        for _ in range(n_out - p if groups > 1 else 0):
+            t.alloc.alloc("carried.s", 1)
+        prev = [t.alloc.alloc("carried.y", 1) for _ in range(n_in if li else 0)]
+        words, acc = None, None
+        for si in (0, k - 1) if k > 1 else (0,):
+            t._begin_pass()
+            s0, s1 = slices[si]
+            if li == 0:
+                refs, free = t._parse_words(s0, s1), True
+            else:
+                if words is None:
+                    words = t._emit_fold("trial", prev)
+                refs, free = words[s0:s1], groups == 1
+            acc = t._emit_slice_group(
+                li, w, refs, 0, "trial", free, acc, si == k - 1
+            )
+            t._end_pass()
+        try:
+            for el in t.elements:
+                el.validate(self.chip.max_parallel_ops)
+        except ProgramConstraintError as e:
+            return None, str(e)
+        for pp in t._pass_plans:
+            if pp.num_elements > self.chip.num_elements:
+                return None, (
+                    f"{pp.num_elements} elements > {self.chip.num_elements} "
+                    "a pass"
+                )
+            if pp.peak_phv_bits > self.chip.phv_bits:
+                return None, (
+                    f"peak PHV usage {pp.peak_phv_bits}b exceeds "
+                    f"{self.chip.phv_bits}b"
+                )
+        return max(pp.num_elements for pp in t._pass_plans), ""
 
 
 def compile_bnn(

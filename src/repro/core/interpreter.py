@@ -7,7 +7,9 @@ element reads the *incoming* PHV, all writes land simultaneously
 
 The interpreter is the correctness witness for the compiler: tests assert
 bit-exact agreement with the mathematical BNN oracle (``core.bnn.forward``)
-over random models and inputs.
+over random models and inputs.  A program compiled into recirculation
+passes runs pass by pass: each pass keeps only its carried header, then
+re-parses its input words from the packet.
 """
 from __future__ import annotations
 
@@ -15,6 +17,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.pipeline import Op, OpCode, PipelineProgram
 
@@ -56,21 +59,36 @@ def _run(prog: PipelineProgram, packets: jax.Array) -> jax.Array:
     batch = packets.shape[0]
     regs = jnp.zeros((batch, prog.num_fields), jnp.uint32)
 
-    # Load the input activation bits into the input fields (parser step).
-    off = 0
-    for f in prog.input_fields:
-        bits = packets[:, off : off + f.width].astype(jnp.uint32)
-        weights = jnp.uint32(1) << jnp.arange(f.width, dtype=jnp.uint32)
-        regs = regs.at[:, f.fid].set(jnp.sum(bits * weights, axis=1, dtype=jnp.uint32))
-        off += f.width
+    if prog.pass_plans is None:
+        offsets = np.cumsum([0] + [f.width for f in prog.input_fields])
+        passes = [(None, tuple(zip(prog.input_fields, offsets)), prog.elements)]
+    else:
+        # Recirculation: a pass starts from its carried header alone, and
+        # its parser extracts its input words from the packet again.
+        passes = [
+            (pp.carried, pp.parsed, prog.elements[slice(*pp.element_range)])
+            for pp in prog.pass_plans
+        ]
+    for carried, parsed, elements in passes:
+        if carried is not None:
+            keep = np.zeros(prog.num_fields, np.uint32)
+            keep[[f.fid for f in carried]] = 1
+            regs = regs * jnp.asarray(keep)[None, :]
+        # Parser step: the input activation bits into the input fields.
+        for f, off in parsed:
+            bits = packets[:, off : off + f.width].astype(jnp.uint32)
+            weights = jnp.uint32(1) << jnp.arange(f.width, dtype=jnp.uint32)
+            regs = regs.at[:, f.fid].set(
+                jnp.sum(bits * weights, axis=1, dtype=jnp.uint32)
+            )
 
-    # Execute elements: read-before-write within each element.
-    for el in prog.elements:
-        if not el.ops:
-            continue
-        vals = [_eval_op(op, regs) for op in el.ops]
-        idx = jnp.array([op.dst.fid for op in el.ops])
-        regs = regs.at[:, idx].set(jnp.stack(vals, axis=1))
+        # Execute elements: read-before-write within each element.
+        for el in elements:
+            if not el.ops:
+                continue
+            vals = [_eval_op(op, regs) for op in el.ops]
+            idx = jnp.array([op.dst.fid for op in el.ops])
+            regs = regs.at[:, idx].set(jnp.stack(vals, axis=1))
 
     # Deparse: output fields -> flat bit vector.
     outs = []

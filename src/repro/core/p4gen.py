@@ -49,6 +49,11 @@ def _stmt(op: Op) -> str:
 
 def generate_p4(prog: PipelineProgram, name: str = "n2net") -> str:
     """Generate P4 source for a compiled program."""
+    if prog.pass_plans:
+        raise NotImplementedError(
+            "P4 emission of recirculation passes (the recirculation header "
+            "and the per-pass parser) is not implemented"
+        )
     out = io.StringIO()
     w = out.write
     w("// Auto-generated by the N2Net compiler (repro.core.compiler).\n")

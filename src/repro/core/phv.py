@@ -50,6 +50,7 @@ class PhvAllocator:
         self.phv_bits = phv_bits
         self._next = 0
         self._live: dict[int, Field] = {}
+        self._live_bits = 0
         self.peak_live_bits = 0
         self.peak_live_fields = 0
 
@@ -57,24 +58,39 @@ class PhvAllocator:
         f = Field(self._next, name, width)
         self._next += 1
         self._live[f.fid] = f
+        self._live_bits += width
         self._update_peak()
         return f
+
+    def detached(self, name: str, width: int) -> Field:
+        """A field id outside the PHV: never live, so it counts toward no
+        stage (the packet's own header bytes, which a parser reads again
+        on every recirculation pass)."""
+        f = Field(self._next, name, width)
+        self._next += 1
+        return f
+
+    def reset_peak(self) -> None:
+        """Start a new pass: the peak restarts from what is live now (the
+        recirculation header carried into the pass)."""
+        self.peak_live_bits = self.live_bits
+        self.peak_live_fields = len(self._live)
 
     def alloc_vector(self, name: str, width: int, count: int) -> list[Field]:
         return [self.alloc(f"{name}[{i}]", width) for i in range(count)]
 
     def free(self, fields) -> None:
         for f in fields:
-            self._live.pop(f.fid, None)
+            if self._live.pop(f.fid, None) is not None:
+                self._live_bits -= f.width
 
     def _update_peak(self) -> None:
-        bits = sum(f.width for f in self._live.values())
-        self.peak_live_bits = max(self.peak_live_bits, bits)
+        self.peak_live_bits = max(self.peak_live_bits, self._live_bits)
         self.peak_live_fields = max(self.peak_live_fields, len(self._live))
 
     @property
     def live_bits(self) -> int:
-        return sum(f.width for f in self._live.values())
+        return self._live_bits
 
     @property
     def num_fields_created(self) -> int:

@@ -109,9 +109,40 @@ class LayerPlan:
     element_range: tuple[int, int]  # [start, end) indices into program.elements
 
 
+@dataclasses.dataclass(frozen=True)
+class PassPlan:
+    """One recirculation pass of a program that one pass cannot hold.
+
+    The pass runs the elements ``element_range`` over a fresh PHV that holds
+    the recirculation header (``carried``: fields an earlier pass wrote and
+    this or a later pass reads) and the input words its parser extracts
+    again from the packet (``parsed``: ``(field, input bit offset)`` pairs;
+    the pipeline never rewrites the packet's header bytes, so every pass
+    can re-parse them).  ``peak_phv_bits`` counts both.
+    """
+
+    element_range: tuple[int, int]
+    parsed: tuple[tuple[Field, int], ...]
+    carried: tuple[Field, ...]
+    peak_phv_bits: int
+
+    @property
+    def num_elements(self) -> int:
+        return self.element_range[1] - self.element_range[0]
+
+    @property
+    def header_bits(self) -> int:
+        return sum(f.width for f in self.carried)
+
+
 @dataclasses.dataclass(eq=False)  # identity eq/hash: programs are cache keys
 class PipelineProgram:
     """A compiled N2Net program: a straight-line sequence of elements.
+
+    A program that one pass cannot hold carries ``pass_plans``: its
+    elements cut into recirculation passes, each within the chip's element,
+    lane and PHV budgets on its own (``compiler`` builds them only where the
+    one-pass schedule breaks a budget).
 
     Programs are built by the compiler and treated as **structurally
     immutable** from the first time they are fingerprinted, executed, or
@@ -135,6 +166,9 @@ class PipelineProgram:
     # from data already hashed by ``fingerprint()`` (XNOR/GE immediates), so
     # it does not participate in the hash.
     packed_layers: tuple[tuple, ...] | None = None
+    # The recirculation passes of a program compiled into passes; None for a
+    # straight-line program.
+    pass_plans: tuple[PassPlan, ...] | None = None
 
     @property
     def num_elements(self) -> int:
@@ -144,7 +178,10 @@ class PipelineProgram:
     def passes(self) -> int:
         """Pipeline passes needed on this program's chip — i.e. ``passes - 1``
         recirculations; a program that fits runs in 1 pass (0 recirculations).
+        A program compiled into passes counts its ``pass_plans``.
         """
+        if self.pass_plans:
+            return len(self.pass_plans)
         return max(1, math.ceil(self.num_elements / self.chip.num_elements))
 
     def fingerprint(self) -> str:
@@ -187,6 +224,12 @@ class PipelineProgram:
                     op.imm,
                 )
             put("|")  # element boundary
+        for pp in self.pass_plans or ():
+            put(
+                "pass",
+                pp.element_range,
+                tuple((f.fid, f.width, off) for f, off in pp.parsed),
+            )
         memo = h.hexdigest()
         self.__dict__["_fingerprint_memo"] = memo
         return memo
@@ -208,12 +251,30 @@ class PipelineProgram:
             raise ProgramConstraintError(
                 f"peak PHV usage {self.peak_phv_bits}b exceeds {self.chip.phv_bits}b"
             )
+        for i, pp in enumerate(self.pass_plans or ()):
+            if pp.num_elements > self.chip.num_elements:
+                raise ProgramConstraintError(
+                    f"pass {i}: {pp.num_elements} elements exceed "
+                    f"{self.chip.num_elements} a pass"
+                )
+            if pp.peak_phv_bits > self.chip.phv_bits:
+                raise ProgramConstraintError(
+                    f"pass {i}: peak PHV usage {pp.peak_phv_bits}b exceeds "
+                    f"{self.chip.phv_bits}b"
+                )
 
     def summary(self) -> str:
         lines = [
             f"chip={self.chip.name} elements={self.num_elements} "
             f"passes={self.passes} peak_phv_bits={self.peak_phv_bits}",
         ]
+        if self.pass_plans:
+            lines.append(
+                "  recirculation header: up to "
+                f"{max(pp.header_bits for pp in self.pass_plans)}b; "
+                f"longest pass {max(pp.num_elements for pp in self.pass_plans)} "
+                "elements"
+            )
         for lp in self.layer_plans:
             lines.append(
                 f"  layer {lp.layer_index}: {lp.n_in}->{lp.n_out} "

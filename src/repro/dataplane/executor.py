@@ -25,7 +25,10 @@ The op-table backends execute in *opcode runs* (``LoweredProgram.
 opcode_runs()``): consecutive elements sharing an opcode set are dispatched
 with an ALU narrowed to exactly those opcodes, so the branchless
 where-select chain collapses for the single-opcode elements the compiler
-emits.
+emits.  A program compiled into recirculation passes runs its opcode runs
+inside one ``lax.scan`` over the passes' stacked tables (:func:`run_hop`):
+each step re-parses the pass's input words from the packet and runs the
+pass, over the one register file that carries the recirculation header.
 
 Streaming (:func:`execute_stream`) re-chunks any packet iterator into
 fixed-size blocks so millions of packets run at constant device memory and a
@@ -35,8 +38,10 @@ single compiled executable.  The stream path is instrumented through
 observability switch is on (see ``docs/OBSERVABILITY.md``).  Each chunk of
 :func:`execute_stream` and :func:`execute` passes through five phase spans,
 ``ingest``, ``h2d``, ``dispatch``, ``d2h`` and ``collect`` (:data:`PHASES`),
-each carrying ``chunk=<k>``; while a JAX profiler session collects they are
-written into its trace, so the device's idle time can be split among them.
+each carrying ``chunk=<k>`` (``dispatch`` also ``passes=<n>``, the
+program's recirculation passes); while a JAX profiler session collects they
+are written into its trace, so the device's idle time can be split among
+them.
 
 Routed parse/deparse (:func:`parse_packets_routed`,
 :func:`deparse_regs_routed`) generalize the parser to per-packet program
@@ -136,17 +141,23 @@ def _device_tables(lp: LoweredProgram) -> _DeviceTables:
             else "dataplane.table_cache_misses_total"
         ).inc()
     if t is None:
+        # A program of passes: (passes, elements a pass, rows) for the scan.
+        shape = (lp.passes, lp.elements_per_pass, lp.max_rows)
+
+        def dev(a: np.ndarray) -> jax.Array:
+            return jnp.asarray(a.reshape(shape) if lp.passes > 1 else a)
+
         t = _DeviceTables(
             ops=(
-                jnp.asarray(lp.opcode),
-                jnp.asarray(lp.dst),
-                jnp.asarray(lp.src0),
-                jnp.asarray(lp.src1),
-                jnp.asarray(lp.imm0),
-                jnp.asarray(lp.imm1),
-                jnp.asarray(lp.mask),
+                dev(lp.opcode),
+                dev(lp.dst),
+                dev(lp.src0),
+                dev(lp.src1),
+                dev(lp.imm0),
+                dev(lp.imm1),
+                dev(lp.mask),
             ),
-            first_write=jnp.asarray(lp.first_write),
+            first_write=dev(lp.first_write),
             io=(
                 jnp.asarray(lp.in_slot_per_bit),
                 jnp.asarray(lp.in_shift_per_bit),
@@ -541,7 +552,9 @@ def run_hop(
     """Run one program (or fabric-hop slice) over parsed register files.
 
     The (num_regs, batch) register file in/out *is* the PHV on the wire —
-    ``fabric.SwitchFabric`` chains hops by threading it through here.
+    ``fabric.SwitchFabric`` chains hops by threading it through here.  A
+    program of recirculation passes runs as one ``lax.scan`` over its
+    passes, each step the pass's opcode runs (its re-parse element first).
     """
     backend = resolve_backend(backend)
     if backend == "packed":
@@ -555,13 +568,28 @@ def run_hop(
 
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
-        return optable_run_segmented(
-            regs, *t.ops, t.first_write, runs=t.runs, interpret=interpret
-        )
-    for start, stop, used in t.runs:
-        regs = run_elements(
-            regs, tuple(a[start:stop] for a in t.ops), used=used
-        )
+
+        def step(regs, ops, first_write):
+            return optable_run_segmented(
+                regs, *ops, first_write, runs=t.runs, interpret=interpret
+            )
+
+    else:
+
+        def step(regs, ops, first_write):
+            for start, stop, used in t.runs:
+                regs = run_elements(
+                    regs, tuple(a[start:stop] for a in ops), used=used
+                )
+            return regs
+
+    if lowered.passes == 1:
+        return step(regs, t.ops, t.first_write)
+
+    def one_pass(regs, tables):
+        return step(regs, *tables), None
+
+    regs, _ = jax.lax.scan(one_pass, regs, (t.ops, t.first_write))
     return regs
 
 
@@ -962,7 +990,7 @@ def execute(
                 block = np.pad(block, ((0, pad), (0, 0)))
         with obs.span("h2d", cat="phase", chunk=k):
             dev = jnp.asarray(block)
-        with obs.span("dispatch", cat="phase", chunk=k):
+        with obs.span("dispatch", cat="phase", chunk=k, passes=lowered.passes):
             res = _run_chunk(lowered, dev, backend, interpret, scan_hops)
         with obs.span("d2h", cat="phase", chunk=k):
             res = np.asarray(res)
@@ -1061,14 +1089,19 @@ def execute_stream(
                 with obs.span(
                     "compile:stream_chunk", cat="compile",
                     backend=backend, packets=chunk_size,
-                ), obs.span("dispatch", cat="phase", chunk=k, warm=True):
+                ), obs.span(
+                    "dispatch", cat="phase", chunk=k, passes=lowered.passes,
+                    warm=True,
+                ):
                     w0 = time.perf_counter()
                     _run_chunk(
                         lowered, dev, backend, interpret, scan_hops
                     ).block_until_ready()
                     warm = warmup = time.perf_counter() - w0
             with obs.span("execute:stream_chunk", cat="execute", packets=n):
-                with obs.span("dispatch", cat="phase", chunk=k):
+                with obs.span(
+                    "dispatch", cat="phase", chunk=k, passes=lowered.passes
+                ):
                     res = _run_chunk(lowered, dev, backend, interpret, scan_hops)
                 with obs.span("d2h", cat="phase", chunk=k):
                     res = np.asarray(res)
@@ -1085,6 +1118,9 @@ def execute_stream(
                 m = obs.registry()
                 m.counter("dataplane.packets_total").inc(n)
                 m.counter("dataplane.chunks_total").inc()
+                m.counter("dataplane.recirc_passes_total").inc(
+                    n * lowered.passes
+                )
                 m.histogram("dataplane.chunk_seconds").observe(dt)
     if obs.enabled() and seconds > 0:
         obs.registry().gauge("dataplane.stream_pps").set(total / seconds)

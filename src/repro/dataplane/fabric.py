@@ -7,7 +7,8 @@ scale-out answer from the follow-on literature is a *chain* of switches, each
 executing a contiguous slice of the program at full line rate, with the PHV
 carried hop to hop in the packet itself.  This module simulates both:
 
-* ``mode="recirculate"`` — one switch, ``ceil(E / num_elements)`` passes;
+* ``mode="recirculate"`` — one switch, ``ceil(E / num_elements)`` passes
+  (a program compiled into passes: its own, each re-parsing the packet);
   analytic throughput divides by the pass count.
 * ``mode="multi_hop"``   — one switch per slice; every switch forwards at
   line rate, so throughput stays at the chip rate and only latency grows.
@@ -124,11 +125,24 @@ class SwitchFabric:
     ) -> "SwitchFabric":
         """Slice ``prog`` into per-switch element ranges of at most
         ``chip.num_elements`` each.  ``chip`` defaults to the program's own
-        target (pass a smaller one to force partitioning in tests)."""
+        target (pass a smaller one to force partitioning in tests).  A
+        program compiled into recirculation passes takes one hop per pass,
+        each hop's table the pass's re-parse element and elements."""
         if mode not in MODES:
             raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
         chip = chip or prog.chip
         lowered = lower_program(prog, compact=compact)
+        if prog.pass_plans:
+            per = lowered.elements_per_pass
+            hops = [
+                SwitchHop(
+                    index=i,
+                    element_range=pp.element_range,
+                    lowered=lowered.slice_elements(i * per, (i + 1) * per),
+                )
+                for i, pp in enumerate(prog.pass_plans)
+            ]
+            return cls(prog, hops, lowered, mode, chip)
         per_hop = chip.num_elements
         if per_hop < 1:
             raise ValueError("chip must have at least one element")

@@ -246,7 +246,10 @@ def execute_fleet(
                 with obs.span(
                     "compile:fleet_chunk", cat="compile",
                     streams=n_streams, packets=n_streams * chunk,
-                ), obs.span("dispatch", cat="phase", chunk=k, warm=True):
+                ), obs.span(
+                    "dispatch", cat="phase", chunk=k, passes=lowered.passes,
+                    warm=True,
+                ):
                     w0 = time.perf_counter()
                     fn(dev).block_until_ready()
                     warm = warmup = time.perf_counter() - w0
@@ -254,7 +257,9 @@ def execute_fleet(
             with obs.span(
                 "execute:fleet_chunk", cat="execute", packets=served
             ):
-                with obs.span("dispatch", cat="phase", chunk=k):
+                with obs.span(
+                    "dispatch", cat="phase", chunk=k, passes=lowered.passes
+                ):
                     res = fn(dev)
                 with obs.span("d2h", cat="phase", chunk=k):
                     res = np.asarray(res)

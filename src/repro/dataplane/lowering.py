@@ -31,6 +31,14 @@ Row layout invariants (relied on by executor + Pallas kernel):
 * slot ``num_slots`` (one past the compacted file) is the always-zero null
   register: padding rows write 0 to it and absent src1 operands read it.
 
+A program compiled into recirculation passes (``PipelineProgram.
+pass_plans``) lowers to ``passes`` equal blocks of elements: each pass's
+re-parse element (``COPY`` rows from the packet's words, which the parser
+loads once and which stay in the register file, into the pass's input
+fields), its elements, then empty elements up to the longest pass.  The
+blocks stack as ``(passes, elements, rows)`` for a scan over the passes;
+run in a straight line they give the same bits.
+
 Cross-module invariants:
 
 * **Bit-exactness** — executing the lowered tables (any backend, any
@@ -49,7 +57,7 @@ from typing import Sequence
 
 import numpy as np
 
-from repro.core.pipeline import Op, OpCode, PipelineProgram
+from repro.core.pipeline import Element, Op, OpCode, PipelineProgram
 
 # Dense ALU opcodes (the executor's instruction set).
 XOR_IMM = 0      # dst = src0 ^ imm0            (COPY, XNOR_IMM)
@@ -238,10 +246,17 @@ class LoweredProgram:
     # Bit-packed execution plan (the "packed" backend); None when the source
     # program carried no layer metadata or after element slicing.
     packed: PackedProgram | None = None
+    # Recirculation passes: the element axis is this many equal blocks, one
+    # pass each (module docstring); 1 for a straight-line program.
+    passes: int = 1
 
     @property
     def num_elements(self) -> int:
         return self.opcode.shape[0]
+
+    @property
+    def elements_per_pass(self) -> int:
+        return self.num_elements // self.passes
 
     @property
     def max_rows(self) -> int:
@@ -293,6 +308,9 @@ class LoweredProgram:
             # A slice is one hop of a layer-level plan; the whole-program
             # packed shortcut no longer applies.
             packed=None,
+            # A slice runs as one straight line (one pass of a program of
+            # passes, or any run of elements: the same bits either way).
+            passes=1,
         )
 
     def with_slot_window(self, offset: int, total_slots: int) -> "LoweredProgram":
@@ -384,16 +402,23 @@ class LoweredProgram:
         the dispatch/compile count stays bounded (a compiled BNN alternates
         marshal/ADD elements; exact runs would mean one dispatch per
         element).  Falls back to one whole-table run when ``opcode_counts``
-        is absent.
+        is absent.  For a program of several passes the runs cover the
+        element positions of one pass, ``[0, elements_per_pass)``, each
+        position's opcodes the union over the passes.
         """
         if self.opcode_counts is None:
             return ((0, self.num_elements, self.used_opcodes()),)
+        counts = self.opcode_counts
         has_pad = self.rows_per_element < self.max_rows
+        if self.passes > 1:
+            per = self.elements_per_pass
+            counts = counts.reshape(self.passes, per, -1).sum(axis=0)
+            has_pad = has_pad.reshape(self.passes, per).any(axis=0)
         runs: list[tuple[int, int, tuple[int, ...]]] = []
         start = 0
         cur: frozenset[int] | None = None
-        for e in range(self.num_elements):
-            used = frozenset(np.nonzero(self.opcode_counts[e])[0].tolist())
+        for e in range(counts.shape[0]):
+            used = frozenset(np.nonzero(counts[e])[0].tolist())
             if has_pad[e] or not used:
                 used |= {SHR_AND_IMM}
             if cur is None:
@@ -404,7 +429,7 @@ class LoweredProgram:
             else:
                 cur = cur | used
         if cur is not None:
-            runs.append((start, self.num_elements, tuple(sorted(cur))))
+            runs.append((start, counts.shape[0], tuple(sorted(cur))))
         return tuple(runs)
 
     def summary(self) -> str:
@@ -412,6 +437,7 @@ class LoweredProgram:
             f"lowered[{self.chip_name}]: elements={self.num_elements} "
             f"ops={self.num_ops} max_rows={self.max_rows} "
             f"regs={self.num_regs} io={self.input_bits}b->{self.output_bits}b"
+            + (f" passes={self.passes}" if self.passes > 1 else "")
         )
 
 
@@ -725,6 +751,9 @@ def _liveness(prog: PipelineProgram) -> tuple[dict[int, int], dict[int, int]]:
         last_use.setdefault(fid, d)  # never-read values die where they're born
     for f in prog.output_fields:
         last_use[f.fid] = len(prog.elements)
+    for pp in prog.pass_plans or ():  # parsed just before the pass's first
+        for f, _ in pp.parsed:
+            def_elem[f.fid] = pp.element_range[0] - 1
     return def_elem, last_use
 
 
@@ -824,6 +853,28 @@ def _lower_op(op: Op, slot: dict[int, int], null: int) -> list[tuple]:
     raise ValueError(f"unknown opcode {code}")  # pragma: no cover
 
 
+def _pass_line(prog: PipelineProgram) -> PipelineProgram:
+    """A program of passes as one straight line of equal blocks: per pass,
+    its re-parse element, its elements, and empty elements up to the
+    longest pass (module docstring)."""
+    packet, off = {}, 0
+    for f in prog.input_fields:
+        packet[off] = f
+        off += f.width
+    per_pass = 1 + max(pp.num_elements for pp in prog.pass_plans)
+    line: list[Element] = []
+    for pp in prog.pass_plans:
+        reparse = Element(stage="reparse")
+        for f, bit in pp.parsed:
+            reparse.add(Op(OpCode.COPY, f, (packet[bit],)))
+        line.append(reparse)
+        line.extend(prog.elements[slice(*pp.element_range)])
+        line.extend(
+            Element(stage="pad") for _ in range(per_pass - 1 - pp.num_elements)
+        )
+    return dataclasses.replace(prog, elements=line, pass_plans=None)
+
+
 def lower_program(prog: PipelineProgram, compact: bool = True) -> LoweredProgram:
     """Lower ``prog`` to dense op-tables.
 
@@ -834,6 +885,9 @@ def lower_program(prog: PipelineProgram, compact: bool = True) -> LoweredProgram
     # The compaction mode changes slot numbering, so it is part of the
     # lowered identity (executor caches are keyed on this fingerprint).
     fingerprint = f"{prog.fingerprint()}:{'compact' if compact else 'full'}"
+    passes = prog.passes if prog.pass_plans else 1
+    if prog.pass_plans:
+        prog = _pass_line(prog)
     num_el = len(prog.elements)
 
     if compact:
@@ -913,4 +967,5 @@ def lower_program(prog: PipelineProgram, compact: bool = True) -> LoweredProgram
         out_shift_per_bit=np.array(out_shift, np.uint32),
         opcode_counts=opcode_counts,
         packed=_packed_program(prog),
+        passes=passes,
     )

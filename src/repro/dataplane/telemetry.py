@@ -68,6 +68,8 @@ def stage_telemetry(
     # source of truth for the liveness rules.
     def_elem, last_use = _liveness(prog)
     widths: dict[int, int] = {f.fid: f.width for f in prog.input_fields}
+    for pp in prog.pass_plans or ():
+        widths.update((f.fid, f.width) for f, _ in pp.parsed)
     for el in prog.elements:
         for op in el.ops:
             widths[op.dst.fid] = op.dst.width

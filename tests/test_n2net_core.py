@@ -129,8 +129,15 @@ def test_max_activation_vector_is_2048():
     assert prog.peak_phv_bits == 4096       # exactly full PHV
     assert prog.num_elements == 25          # Table 1 right edge
 
+    # Past 2048 bits one pass no longer holds the neuron: it recirculates,
+    # each pass over a fan-in slice within the PHV (paper §2-3).
     big = bnn.BnnSpec((4096, 1))
-    bad = bnn.init_params(big, jax.random.PRNGKey(0))
+    wide = compile_bnn([np.asarray(w) for w in bnn.init_params(big, jax.random.PRNGKey(0))])
+    assert wide.passes > 1 and wide.pass_plans is not None
+    assert max(pp.peak_phv_bits for pp in wide.pass_plans) <= 4096
+    # A hidden layer wider than the PHV cannot ride in the recirculation
+    # header: that network the chip cannot run.
+    bad = bnn.init_params(bnn.BnnSpec((32, 8192, 1)), jax.random.PRNGKey(0))
     with pytest.raises(ProgramConstraintError):
         compile_bnn([np.asarray(w) for w in bad])
 

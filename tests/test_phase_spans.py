@@ -15,6 +15,7 @@ import pytest
 
 from repro import obs
 from repro.core import bnn, compile_bnn
+from repro.core.pipeline import ChipSpec
 from repro.dataplane import ExecutionPlan, execute, execute_stream, lower_program
 from repro.dataplane.executor import PHASES
 from repro.dataplane.fleet import execute_fleet
@@ -177,3 +178,35 @@ def test_lowerings_are_counted_under_dispatch(model):
     execute_stream(lp, [_packets(200)], backend="jnp", chunk_size=95)
     after = obs.registry().counter("jax.lowerings_total", span="dispatch").value
     assert after == before  # the listener goes with the switch
+
+
+def test_recirculating_stream_lowers_once_and_carries_its_passes(tmp_path):
+    """A program of recirculation passes: its stream lowers the dispatch at
+    the warm call only, every ``dispatch`` span carries ``passes``, and
+    ``dataplane.recirc_passes_total`` counts packets times passes."""
+    chip = ChipSpec(phv_bits=1024, num_elements=16, max_parallel_ops=32, name="small")
+    params = bnn.init_params(bnn.BnnSpec((256, 16, 8)), jax.random.PRNGKey(6))
+    lp = lower_program(compile_bnn([np.asarray(w) for w in params], chip))
+    assert lp.passes > 1
+    x = np.random.default_rng(4).integers(0, 2, (6 * 64, 256)).astype(np.int32)
+
+    def stream(packets):
+        return execute_stream(lp, [packets], backend="jnp", chunk_size=64, collect=True)
+
+    def lowerings():
+        return obs.registry().counter("jax.lowerings_total", span="dispatch").value
+
+    obs.enable(reset=True)
+    stream(x[:64])
+    one = lowerings()
+    assert one >= 1
+    res, events = _profiled(tmp_path, stream, x)
+    assert res.chunks == 6 and lowerings() == one
+    np.testing.assert_array_equal(res.outputs, np.asarray(bnn.forward(params, x)))
+    dispatch = [e for e in events if e[0] == "dispatch" and "chunk" in e[3]]
+    assert len(dispatch) == res.chunks + 1  # the warm call's too
+    assert {int(e[3]["passes"]) for e in dispatch} == {lp.passes}
+    records = [r for r in obs.tracer().records if r.name == "dispatch"]
+    assert {r.args["passes"] for r in records} == {lp.passes}
+    total = obs.registry().counter("dataplane.recirc_passes_total").value
+    assert total == (64 + x.shape[0]) * lp.passes

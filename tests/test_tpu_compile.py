@@ -168,3 +168,17 @@ def test_sharded_fleet_compiles_for_four_v5e_chips(topo, backend):
     compiled = jax.jit(fn).lower(blocks).compile()
     assert len(compiled.output_shardings.device_set) == 4
     assert ("tpu_custom_call" in compiled.as_text()) == (backend == "pallas")
+
+
+def test_recirculation_chunk_compiles_for_v5e(one_chip):
+    """Paper Table 1's 2048-bit activations, 2048-64-32 in recirculation
+    passes: the pass scan with its opcode runs' kernels inside the one
+    module ``jit_stream_chunk``, at the benchmark cell's chunk."""
+    rng = np.random.default_rng(16)
+    weights = [rng.integers(0, 2, (64, 2048)), rng.integers(0, 2, (32, 64))]
+    lp = lower_program(compile_bnn(weights))
+    assert lp.passes > 1
+    packets = jax.ShapeDtypeStruct((8192, 2048), jnp.int32, sharding=one_chip)
+    text = executor._chunk_fn(lp, "pallas", False).lower(packets).compile().as_text()
+    assert text.startswith("HloModule jit_stream_chunk")
+    assert text.count('custom_call_target="tpu_custom_call"') == len(lp.opcode_runs())
