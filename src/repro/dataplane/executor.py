@@ -309,7 +309,8 @@ def parse_packets(packets: jax.Array, in_slot, in_shift, *, num_regs: int):
 
     The register file is transposed — registers on the leading axis — so the
     executor's per-row gathers and scatters are contiguous row copies instead
-    of strided column accesses (and the layout matches the Pallas kernel's).
+    of strided column accesses (and the Pallas kernel's sublane-dense file
+    is its row-major reshape, ``optable_exec.to_dense``).
     """
     pkt = packets.astype(jnp.uint32).T  # (input_bits, batch)
     regs = jnp.zeros((num_regs, packets.shape[0]), jnp.uint32)
@@ -517,21 +518,25 @@ def run_hops_scanned(
         first_write = jnp.asarray(stacked.first_write)
         used = stacked.used
         if backend == "pallas":
-            from repro.kernels.optable_exec import optable_run
+            from repro.kernels import optable_exec as ox
 
             interp = bool(interpret)
 
             @jax.jit
             def scanned(regs, tabs, fw):
-                def hop(regs, tbl):
+                def hop(dense, tbl):
                     t, f = tbl
                     return (
-                        optable_run(regs, *t, f, used=used, interpret=interp),
+                        ox.optable_run_dense(
+                            dense, *t, f, used=used, interpret=interp
+                        ),
                         None,
                     )
 
-                regs, _ = jax.lax.scan(hop, regs, (tabs, fw))
-                return regs
+                # One relayout each way around the whole hop scan.
+                dense = ox.to_dense(regs, fw.shape[-1])
+                dense, _ = jax.lax.scan(hop, dense, (tabs, fw))
+                return ox.from_dense(dense, regs.shape[1])
 
             entry = (lambda r: scanned(r, tables, first_write))
         else:
@@ -564,14 +569,14 @@ def run_hop(
         )
     t = _device_tables(lowered)
     if backend == "pallas":
-        from repro.kernels.optable_exec import optable_run_segmented
+        from repro.kernels import optable_exec as ox
 
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
 
-        def step(regs, ops, first_write):
-            return optable_run_segmented(
-                regs, *ops, first_write, runs=t.runs, interpret=interpret
+        def step(dense, ops, first_write):
+            return ox.optable_run_segmented_dense(
+                dense, *ops, first_write, runs=t.runs, interpret=interpret
             )
 
     else:
@@ -583,14 +588,21 @@ def run_hop(
                 )
             return regs
 
-    if lowered.passes == 1:
-        return step(regs, t.ops, t.first_write)
+    def all_passes(regs):
+        if lowered.passes == 1:
+            return step(regs, t.ops, t.first_write)
 
-    def one_pass(regs, tables):
-        return step(regs, *tables), None
+        def one_pass(regs, tables):
+            return step(regs, *tables), None
 
-    regs, _ = jax.lax.scan(one_pass, regs, (t.ops, t.first_write))
-    return regs
+        regs, _ = jax.lax.scan(one_pass, regs, (t.ops, t.first_write))
+        return regs
+
+    if backend != "pallas":
+        return all_passes(regs)
+    # The kernel's dense layout, made once around every pass.
+    dense = all_passes(ox.to_dense(regs, lowered.max_rows))
+    return ox.from_dense(dense, regs.shape[1])
 
 
 # ---------------------------------------------------------------------------
@@ -731,6 +743,7 @@ def routed_fn(
         @jax.jit
         def fn(packets: jax.Array, program_ids: jax.Array) -> jax.Array:
             regs = parse(packets, program_ids)
+            # Relaid out to the kernel's dense file once, around every run.
             regs = optable_run_segmented(
                 regs, *t.ops, t.first_write, runs=runs, interpret=interp
             )
@@ -935,6 +948,16 @@ def _chunk_fn(
     return fn
 
 
+def _kernel_pad(lp: LoweredProgram, backend: str, batch: int) -> int:
+    """Packet columns the op-table kernel pads a ``batch``-packet dispatch
+    by to fill its tiles (0 off the ``pallas`` backend)."""
+    if backend != "pallas":
+        return 0
+    from repro.kernels.optable_exec import pad_packets
+
+    return pad_packets(batch, lp.num_regs, lp.max_rows)
+
+
 def _run_chunk(
     lp: LoweredProgram,
     packets: jax.Array,
@@ -992,6 +1015,10 @@ def execute(
             dev = jnp.asarray(block)
         with obs.span("dispatch", cat="phase", chunk=k, passes=lowered.passes):
             res = _run_chunk(lowered, dev, backend, interpret, scan_hops)
+        if obs.enabled():
+            obs.registry().counter("dataplane.kernel_pad_packets_total").inc(
+                _kernel_pad(lowered, backend, size)
+            )
         with obs.span("d2h", cat="phase", chunk=k):
             res = np.asarray(res)
         with obs.span("collect", cat="phase", chunk=k):
@@ -1120,6 +1147,9 @@ def execute_stream(
                 m.counter("dataplane.chunks_total").inc()
                 m.counter("dataplane.recirc_passes_total").inc(
                     n * lowered.passes
+                )
+                m.counter("dataplane.kernel_pad_packets_total").inc(
+                    _kernel_pad(lowered, backend, chunk_size)
                 )
                 m.histogram("dataplane.chunk_seconds").observe(dt)
     if obs.enabled() and seconds > 0:

@@ -23,6 +23,7 @@ from repro.dataplane import (
 )
 from repro.dataplane.executor import _rechunk
 from repro.dataplane.lowering import POPCNT, SHL_IMM
+from repro.kernels import optable_exec
 
 MODELS = [(8, 4), (32, 64, 32), (33, 17, 9), (96, 40, 12, 5)]
 
@@ -120,13 +121,59 @@ def test_executor_native_popcnt_chip():
     )
 
 
-def test_executor_pallas_kernel_matches():
+# One packet, a part of a sublane, whole sublanes short of a tile, one
+# (8, 128) tile, a tile and one, and several blocks of full tiles.
+@pytest.mark.parametrize("n", [1, 7, 70, 1000, 1024, 1025, 8192])
+def test_executor_pallas_kernel_matches(n):
     _, prog = _compiled((16, 8, 4))
     lp = lower_program(prog)
-    x = _packets(70, 16, seed=3)  # non-multiple of the batch block: pads
+    x = _packets(n, 16, seed=3)
     want = execute(lp, x, backend="jnp")
     got = execute(lp, x, backend="pallas", interpret=True)
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("batch", [1, 7, 1000, 1025, 8192])
+def test_dense_register_file_is_the_row_major_reshape(batch):
+    """Packet ``p`` sits at sublane ``p // lanes``, lane ``p % lanes``; the
+    round trip gives the register file back unchanged."""
+    num_regs, rows = 129, 128
+    regs = jnp.asarray(
+        np.random.default_rng(batch).integers(0, 1 << 32, (num_regs, batch)),
+        jnp.uint32,
+    )
+    dense = optable_exec.to_dense(regs, rows)
+    lanes = optable_exec.dense_lanes(batch, num_regs, rows)
+    assert dense.shape == (num_regs, 8, lanes)
+    assert 8 * lanes - batch == optable_exec.pad_packets(batch, num_regs, rows)
+    p = np.arange(batch)
+    np.testing.assert_array_equal(
+        np.asarray(dense)[:, p // lanes, p % lanes], np.asarray(regs)
+    )
+    back = optable_exec.from_dense(dense, batch)
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(regs))
+
+
+def test_dense_kernel_refuses_a_file_that_does_not_tile():
+    """Past one block the lanes must be whole tiles, as ``to_dense`` pads
+    them; a file of 1,000 lanes at 129 registers would drop packets."""
+    tables = [jnp.zeros((2, 128), jnp.int32)] * 8
+    with pytest.raises(ValueError, match="to_dense"):
+        optable_exec.optable_run_dense(
+            jnp.zeros((129, 8, 1000), jnp.uint32), *tables, interpret=True
+        )
+
+
+def test_kernel_pads_to_sublanes_and_to_tiles_only_past_one_block():
+    # 129 registers and 128 rows (32-64-32) fit 512 lanes a block in the
+    # VMEM budget: up to 4,096 packets take one block, padded to 8.
+    assert optable_exec.pad_packets(1000, 129, 128) == 0
+    assert optable_exec.pad_packets(1001, 129, 128) == 7
+    assert optable_exec.pad_packets(4096, 129, 128) == 0
+    # Past one block, whole 128-lane tiles: 5,000 packets -> 640 lanes.
+    assert optable_exec.pad_packets(5000, 129, 128) == 5120 - 5000
+    assert optable_exec.pad_packets(8192, 221, 128) == 0
+    assert optable_exec.pad_packets(32768, 129, 128) == 0
 
 
 def test_executor_chunked_equals_single_shot():

@@ -38,8 +38,8 @@ from strategies import (
 
 from repro.core import bitops, bnn, interpreter
 from repro.core.compiler import compile_bnn
-from repro.core.pipeline import ProgramConstraintError
-from repro.dataplane import executor
+from repro.core.pipeline import ChipSpec, ProgramConstraintError
+from repro.dataplane import executor, fleet as fleet_mod
 from repro.dataplane.lowering import pack_bit_rows
 from repro.dataplane.multitenant import AdmissionError, SwitchScheduler
 from repro.train import bnn_trainer
@@ -233,6 +233,51 @@ def test_threshold_extremes_never_and_always_fire():
     for backend in BACKENDS:
         np.testing.assert_array_equal(
             executor.execute(lp, packets, backend=backend), oracle
+        )
+
+
+# Programs at the op-table kernel's layout edges: (layer sizes, chip or
+# None for the default, packets).  1,025 packets fill a tile and one; on
+# the 24-element chip 384 inputs take two recirculation passes; the 40
+# sign bits of 8-40 fold into words through FOLD continuation rows.
+_SMALL_CHIP = ChipSpec(
+    phv_bits=1024, num_elements=24, max_parallel_ops=32, name="small"
+)
+_KERNEL_LAYOUT_CASES = {
+    "32-64-32": ((32, 64, 32), None, 1025),
+    "recirculation-2pass": ((384, 2), _SMALL_CHIP, 300),
+    "fold-rows": ((8, 40), None, 1000),
+}
+
+
+@pytest.mark.parametrize("fleet", [False, True], ids=["single", "fleet-vmapped"])
+@pytest.mark.parametrize("case", sorted(_KERNEL_LAYOUT_CASES))
+def test_backends_match_oracle_across_kernel_layouts(case, fleet):
+    """Every backend equals the oracle on programs at the kernel's layout
+    edges, alone and as the fleet's vmapped chunk body over three
+    streams."""
+    sizes, chip, n = _KERNEL_LAYOUT_CASES[case]
+    rng = np.random.default_rng(17)
+    ws = [
+        rng.integers(0, 2, (sizes[i + 1], sizes[i])).astype(np.int32)
+        for i in range(len(sizes) - 1)
+    ]
+    lp = (compile_bnn(ws, chip) if chip else compile_bnn(ws)).lower()
+    if case == "recirculation-2pass":
+        assert lp.passes == 2
+    if case == "fold-rows":
+        assert (lp.first_write == 0).sum() >= 32
+    packets = rng.integers(0, 2, (n, sizes[0])).astype(np.int32)
+    oracle = np.asarray(bnn.forward(ws, packets))
+    for backend in BACKENDS:
+        if fleet:
+            streams = packets[: n - n % 3].reshape(3, n // 3, sizes[0])
+            out = np.asarray(fleet_mod.fleet_fn(lp, backend=backend)(streams))
+            want = oracle[: n - n % 3].reshape(3, n // 3, -1)
+        else:
+            out, want = executor.execute(lp, packets, backend=backend), oracle
+        np.testing.assert_array_equal(
+            out, want, err_msg=f"backend {backend!r} diverges from oracle"
         )
 
 

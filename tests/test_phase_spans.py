@@ -210,3 +210,25 @@ def test_recirculating_stream_lowers_once_and_carries_its_passes(tmp_path):
     assert {r.args["passes"] for r in records} == {lp.passes}
     total = obs.registry().counter("dataplane.recirc_passes_total").value
     assert total == (64 + x.shape[0]) * lp.passes
+
+
+def test_kernel_pad_packets_counts_the_kernels_tile_padding(model):
+    """``dataplane.kernel_pad_packets_total``: the packet columns the
+    op-table kernel pads each dispatch by.  An 8,192-packet chunk fills
+    whole tiles; a request batch pads only up to the next eight packets."""
+    params, lp = model
+
+    def pad_total():
+        return obs.registry().counter("dataplane.kernel_pad_packets_total").value
+
+    obs.enable(reset=True)
+    x = _packets(2 * 8192)
+    res = execute_stream(lp, [x], backend="pallas", chunk_size=8192, collect=True)
+    assert res.chunks == 2 and pad_total() == 0
+    execute(lp, x[:1000], backend="pallas")
+    assert pad_total() == 0
+    out = execute(lp, x[:1001], backend="pallas")
+    assert pad_total() == 7
+    np.testing.assert_array_equal(out, np.asarray(bnn.forward(params, x[:1001])))
+    execute(lp, x[:1001], backend="jnp")  # no kernel, no pad
+    assert pad_total() == 7

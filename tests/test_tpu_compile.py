@@ -12,6 +12,7 @@ every test worker collects the same tests and only the worker running this
 file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -182,3 +183,56 @@ def test_recirculation_chunk_compiles_for_v5e(one_chip):
     text = executor._chunk_fn(lp, "pallas", False).lower(packets).compile().as_text()
     assert text.startswith("HloModule jit_stream_chunk")
     assert text.count('custom_call_target="tpu_custom_call"') == len(lp.opcode_runs())
+
+
+def _computations(text: str) -> dict[str, str]:
+    """An HLO module's text, split into its computations by name."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = line.removeprefix("ENTRY ")
+        if head.startswith("%") and line.rstrip().endswith("{"):
+            name = head[1:].split(" ", 1)[0]
+            comps[name] = ""
+        elif name is not None:
+            comps[name] += line + "\n"
+    return comps
+
+
+def _reachable(comps: dict[str, str], root: str) -> set[str]:
+    """``root`` and every computation it calls, transitively."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo += set(re.findall(r"%([\w.\-]+)", comps[name])) & comps.keys()
+    return seen
+
+
+def test_recirculation_relayout_sits_outside_the_pass_scan(one_chip):
+    """``wide2048-uniform``'s chunk: the register file is relaid out to the
+    kernel's dense ``(num_regs, 8, lanes)`` form once a chunk, before the
+    pass scan's ``while``; its body runs only the kernels on the dense file
+    and never sees the flat ``(num_regs, batch)`` one."""
+    rng = np.random.default_rng(16)
+    weights = [rng.integers(0, 2, (64, 2048)), rng.integers(0, 2, (32, 64))]
+    lp = lower_program(compile_bnn(weights))
+    chunk = 8192
+    packets = jax.ShapeDtypeStruct((chunk, 2048), jnp.int32, sharding=one_chip)
+    text = executor._chunk_fn(lp, "pallas", False).lower(packets).compile().as_text()
+    comps = _computations(text)
+    whiles = [
+        line.split("body=%", 1)[1].split(",", 1)[0].split(" ", 1)[0]
+        for body in comps.values()
+        for line in body.splitlines()
+        if " while(" in line
+    ]
+    assert len(whiles) == 1
+    inside = "".join(comps[c] for c in _reachable(comps, whiles[0]))
+    flat = f"u32[{lp.num_regs},{chunk}]"
+    dense = f"u32[{lp.num_regs},8,{chunk // 8}]"
+    assert inside.count('custom_call_target="tpu_custom_call"') == len(
+        lp.opcode_runs()
+    )
+    assert dense in inside and flat not in inside
+    assert flat in text  # parse and deparse, outside the scan
